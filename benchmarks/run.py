@@ -30,6 +30,9 @@ def main() -> None:
                     help="write aggregated machine-readable results here")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     t0 = time.time()
     results: dict[str, dict] = {}
 

@@ -12,8 +12,10 @@ Three records, appended to the BENCH trajectory:
   * **lane_ladder** — the size-sharded trainer layout
     (``trainer.shard_lane_layout`` + per-device programs trimmed to their
     shard max) against the seed's global-``n_max`` program, at
-    D in {1, 2, 4, 8} shards on 8 virtual XLA host devices (one
-    subprocess per rung so ``XLA_FLAGS`` never leaks).  Throughput is
+    D in {1, 2, 4, 8} shards on 8 virtual CPU devices (one subprocess
+    per rung, each pinned to ``JAX_PLATFORMS=cpu`` so ``XLA_FLAGS`` never
+    leaks and a rung never contends for a chip; these are never chip
+    numbers).  Throughput is
     TRUE lane work per second — sum over pairs of ``n_i^2 * G * C``
     solver-units, identical across rungs — so rung ratios measure
     exactly the padded-work this layout removes.  ``--assert-scaling``
@@ -276,7 +278,9 @@ def run_lane_ladder() -> dict:
     """D in {1, 2, 4, 8} size-sharded rungs, one subprocess each."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     src = os.path.join(root, "src")
-    env = dict(os.environ, PYTHONPATH=src)
+    # Virtual CPU devices: the child never reaches for a chip the parent
+    # process may already hold.
+    env = dict(os.environ, PYTHONPATH=src, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     rungs = []
     for d in (1, 2, 4, 8):

@@ -22,8 +22,9 @@ numbers, appended to the BENCH trajectory):
 Two mesh-era phases ride along (PR 10, DESIGN.md §12):
 
   * **device ladder** — the engine on a ``make_serving_mesh`` at
-    d in {1, 2, 4, 8} virtual host devices (one subprocess per rung so
-    ``XLA_FLAGS`` never leaks), serving full ``bucket x d`` padded
+    d in {1, 2, 4, 8} virtual CPU devices (one subprocess per rung, each
+    pinned to ``JAX_PLATFORMS=cpu`` so ``XLA_FLAGS`` never leaks and a
+    rung never contends for a chip; these are never chip numbers), serving full ``bucket x d`` padded
     batches through the shard_map data-parallel forward.  Each rung
     re-asserts per-device-slice bit identity against the single-device
     program, measures wall throughput AND the per-device slice time, and
@@ -394,7 +395,9 @@ def run_device_ladder(seed: int = 0) -> dict:
     """d in {1, 2, 4, 8} mesh-sharded engine rungs, one subprocess each."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     src = os.path.join(root, "src")
-    env = dict(os.environ, PYTHONPATH=src)
+    # Virtual CPU devices: the child never reaches for a chip the parent
+    # process may already hold.
+    env = dict(os.environ, PYTHONPATH=src, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     rungs = []
     for d in LADDER_DEVICES:

@@ -391,7 +391,7 @@ def _bank_scores(bank: _KernelBank, xv: jnp.ndarray,
     def one(sv, gamma, scale, shift, cpos, cneg, bpos, bneg, off):
         k = _pair_kernel(bank, xv, sv, gamma, scale, shift, use_pallas,
                          interpret=interpret)
-        rails = k @ jnp.stack([cpos, cneg], axis=1)      # (n, 2)
+        rails = kern.matmul(k, jnp.stack([cpos, cneg], axis=1))  # (n, 2)
         return (rails[:, 0] + bpos) - (rails[:, 1] + bneg) + off
 
     return jax.vmap(one, out_axes=1)(
@@ -418,7 +418,8 @@ def _all_scores(x: jnp.ndarray, linear_banks, kernel_banks,
 
     cols = []
     for bank in linear_banks:
-        cols.append(xq(bank.input_bits) @ bank.w.T + bank.b[None, :])
+        cols.append(kern.matmul(xq(bank.input_bits), bank.w.T)
+                    + bank.b[None, :])
     for bank in kernel_banks:
         cols.append(_bank_scores(bank, xq(bank.input_bits), use_pallas,
                                  interpret=interpret))
@@ -650,8 +651,8 @@ def _gather_pair_scores(p, linear_banks, kernel_banks, row_maps, xq_cache,
         def one(xi, sv, gamma, scale, shift, cpos, cneg, bpos, bneg, off):
             k = _pair_kernel(bank, xi[None, :], sv, gamma, scale, shift,
                              False)[0]                     # (m_t,)
-            return (jnp.dot(k, cpos) + bpos) \
-                - (jnp.dot(k, cneg) + bneg) + off
+            return (kern.matmul(k, cpos) + bpos) \
+                - (kern.matmul(k, cneg) + bneg) + off
 
         s = jax.vmap(one)(
             xq_cache[bank.input_bits], bank.sv[:, :m_t][r], bank.gamma[r],
@@ -1212,6 +1213,21 @@ def _key_data(key: jax.Array) -> np.ndarray:
         return np.asarray(key)
 
 
+#: Bytes one ``(variants, n, P, M)`` f32 temporary of the analog variant
+#: lanes may take.  The measured-curve lookups materialize index and
+#: fraction tensors of that shape (the gather ends XLA's fusion), so B
+#: variants over n rows ask for B*n*P*M*4 bytes each: 26.6 GB at har12
+#: size (256 variants x 512 rows x 66 pairs) on a 16 GB chip.  Past this
+#: bound the variant axis runs in sequential slices of the largest
+#: count that fits.
+MC_LANE_BYTES = 1 << 28
+
+
+def _variant_batch(n_rows: int, n_pairs: int, m_max: int) -> int:
+    """Variants per slice of the analog lanes under ``MC_LANE_BYTES``."""
+    return max(1, MC_LANE_BYTES // (4 * n_rows * n_pairs * m_max))
+
+
 def _bank_scores_mc(bank: _KernelBank, bv: _BankVariants, xv: jnp.ndarray,
                     use_pallas: bool, include_nominal: bool,
                     interpret: Optional[bool] = None) -> jnp.ndarray:
@@ -1238,7 +1254,7 @@ def _bank_scores_mc(bank: _KernelBank, bv: _BankVariants, xv: jnp.ndarray,
             vshift, vgain):
         k = _pair_kernel(bank, xv, sv, gamma, scale, shift, use_pallas,
                          vshift=vshift, vgain=vgain, interpret=interpret)
-        rails = k @ jnp.stack([cpos, cneg], axis=1)      # (n, 2)
+        rails = kern.matmul(k, jnp.stack([cpos, cneg], axis=1))  # (n, 2)
         return (rails[:, 0] + bpos) - (rails[:, 1] + bneg) + off
 
     def one_variant(vshift, vgain, vcpos, vcneg, voff):
@@ -1248,9 +1264,14 @@ def _bank_scores_mc(bank: _KernelBank, bv: _BankVariants, xv: jnp.ndarray,
             vshift, vgain)
 
     lo = 1 if include_nominal else 0
-    var = jax.vmap(one_variant)(
-        bv.shift[lo:], bv.gain[lo:], bv.coef_pos[lo:],
-        bv.coef_neg[lo:], bv.offset[lo:])
+    lanes = (bv.shift[lo:], bv.gain[lo:], bv.coef_pos[lo:],
+             bv.coef_neg[lo:], bv.offset[lo:])
+    batch = _variant_batch(*xv.shape[:1], *bank.sv.shape[:2])
+    if lanes[0].shape[0] <= batch:
+        var = jax.vmap(one_variant)(*lanes)
+    else:
+        var = jax.lax.map(lambda a: one_variant(*a), lanes,
+                          batch_size=batch)
     if not include_nominal:
         return var
     nom = _bank_scores(bank, xv, use_pallas, interpret=interpret)
@@ -1276,7 +1297,7 @@ def _all_scores_mc(x: jnp.ndarray, linear_banks, kernel_banks,
 
     cols = []
     for bank in linear_banks:
-        c = xq(bank.input_bits) @ bank.w.T + bank.b[None, :]
+        c = kern.matmul(xq(bank.input_bits), bank.w.T) + bank.b[None, :]
         cols.append(jnp.broadcast_to(c[None], (n_variants,) + c.shape))
     for bank, bv in zip(kernel_banks, bank_variants):
         if bv is None:
@@ -1726,7 +1747,6 @@ class StreamingMCMachine:
     # -- sharded step (variant axis over a mesh) -----------------------------
 
     def _make_sharded_step(self, mesh):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         from repro.launch.mesh import VARIANTS_AXIS
@@ -1767,10 +1787,10 @@ class StreamingMCMachine:
                 log_ref=ref)
             return mcstream.merge_stream(state, agg)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             local_step, mesh=mesh,
             in_specs=(rep, rep, shd, shd, rep, rep, rep, shd),
-            out_specs=rep, check_rep=False)
+            out_specs=rep, check_vma=False)
         return jax.jit(fn, donate_argnums=(0,))
 
     def _sharded_step(self, mesh):

@@ -351,7 +351,6 @@ class ShardedFleetForward:
     """
 
     def __init__(self, fleet: FleetMachine, mesh):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import NamedSharding, PartitionSpec
 
         from repro.launch.mesh import SERVING_AXIS
@@ -365,9 +364,11 @@ class ShardedFleetForward:
         self.n_devices = int(mesh.shape[SERVING_AXIS])
         spec = PartitionSpec(SERVING_AXIS)
         self._sharding = NamedSharding(mesh, spec)
+        # check_vma off: Pallas banks (use_pallas, as on the TPU) declare
+        # their outputs without a varying-mesh-axes annotation.
         self._labels_jit = jax.jit(
-            shard_map(fleet._labels, mesh=mesh, in_specs=(spec, spec),
-                      out_specs=spec),
+            jax.shard_map(fleet._labels, mesh=mesh, in_specs=(spec, spec),
+                          out_specs=spec, check_vma=False),
             in_shardings=(self._sharding, self._sharding),
             out_shardings=self._sharding,
             donate_argnums=(1,))

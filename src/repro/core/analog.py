@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import kernels as kern
 from repro.core.kernels import N_SLOPE, V_T
 from repro.core.svm import SVMModel
 
@@ -599,8 +600,8 @@ class AnalogBinaryClassifier:
         a = self.hw.alpha_realized(dva)
         cur = k * a[None, :]
         pos = jnp.asarray(self.support_y > 0, jnp.float32)
-        i_plus = cur @ pos + jnp.maximum(self.bias_hw, 0.0)
-        i_minus = cur @ (1.0 - pos) + jnp.maximum(-self.bias_hw, 0.0)
+        i_plus = kern.matmul(cur, pos) + jnp.maximum(self.bias_hw, 0.0)
+        i_minus = kern.matmul(cur, 1.0 - pos) + jnp.maximum(-self.bias_hw, 0.0)
         return i_plus, i_minus
 
     def predict_bits(self, x: np.ndarray) -> np.ndarray:
@@ -653,7 +654,8 @@ class AnalogBinaryClassifier:
         # path — the compiled MonteCarloMachine is the throughput path.
         bias_p = jnp.maximum(self.bias_hw, 0.0)
         bias_n = jnp.maximum(-self.bias_hw, 0.0)
-        rows = [(cur[i] @ pos + bias_p) - (cur[i] @ neg + bias_n)
+        rows = [(kern.matmul(cur[i], pos) + bias_p)
+                - (kern.matmul(cur[i], neg) + bias_n)
                 for i in range(cur.shape[0])]
         return jnp.stack(rows) + t.comp_offset[:, None]
 

@@ -16,24 +16,38 @@ tiled version and is validated against these functions.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
+
+#: Precision of every f32 contraction on the SVM path, the Pallas tiles
+#: and their jnp oracles included.  The TPU's default for f32 is one bf16
+#: MXU pass (~1e-2 relative on a d = 5 dot), far outside the
+#: comparator-tie epsilon (DESIGN.md §1.4); ``HIGHEST`` restores f32.  On
+#: the CPU it changes nothing.
+PRECISION = jax.lax.Precision.HIGHEST
 
 # Thermal voltage at 300 K (V) and typical IGZO subthreshold slope factor.
 V_T: float = 0.02585
 N_SLOPE: float = 1.38
 
 
+def matmul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """``a @ b`` at :data:`PRECISION`: every contraction of the SVM path
+    goes here."""
+    return jnp.matmul(a, b, precision=PRECISION)
+
+
 def pairwise_sq_dists(x: jnp.ndarray, z: jnp.ndarray) -> jnp.ndarray:
     """||x_i - z_j||^2 for x:(n,d), z:(m,d) -> (n,m), matmul-dominant form."""
     xx = jnp.sum(x * x, axis=-1)[:, None]
     zz = jnp.sum(z * z, axis=-1)[None, :]
-    xz = x @ z.T
+    xz = matmul(x, z.T)
     return jnp.maximum(xx + zz - 2.0 * xz, 0.0)
 
 
 def linear_kernel(x: jnp.ndarray, z: jnp.ndarray) -> jnp.ndarray:
     """K(x, z) = x.z  (paper Sec. II-A)."""
-    return x @ z.T
+    return matmul(x, z.T)
 
 
 def rbf_kernel(x: jnp.ndarray, z: jnp.ndarray, gamma: jnp.ndarray) -> jnp.ndarray:

@@ -35,10 +35,9 @@ Contracts
   live on the lattice ``k / n_val``, so with ``n_bins = n_val + 1``
   (up to :data:`MAX_HIST_BINS`) the sketch is *exact*, not approximate.
 
-Host-side helpers: Wilson / Clopper-Pearson binomial bounds (the latter
-gated on scipy, with a Wilson fallback) and the scrambled-Sobol /
-Latin-hypercube chunk samplers (:class:`QMCSampler`), both seeded
-deterministically from stored jax key data.  Sobol chunks are generated
+Host-side helpers: Wilson / Clopper-Pearson binomial bounds and the
+scrambled-Sobol / Latin-hypercube chunk samplers (:class:`QMCSampler`),
+both seeded deterministically from stored jax key data.  Sobol chunks are generated
 with ``fast_forward`` so draw ``v`` depends only on the *global* variant
 index — the streamed sequence is invariant to the chunk size, mirroring
 the ``fold_in``-keyed iid draws.
@@ -51,6 +50,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.core import kernels as kern
 
 #: Default two-sided confidence level of the yield interval.
 DEFAULT_CONFIDENCE = 0.95
@@ -189,8 +190,8 @@ def chunk_aggregates(center: jnp.ndarray, acc: jnp.ndarray, w: jnp.ndarray,
         log_ref = jnp.zeros((), jnp.float32)
     return ChunkAgg(
         n_c=jnp.sum(valid), w_c=jnp.sum(wv), w2_c=jnp.sum(wv * wv),
-        s1=wv @ dc, s2=wv @ (dc * dc),
-        exceed=wv @ (acc >= floor).astype(jnp.float32),
+        s1=kern.matmul(wv, dc), s2=kern.matmul(wv, dc * dc),
+        exceed=kern.matmul(wv, (acc >= floor).astype(jnp.float32)),
         amin=jnp.min(masked_lo, axis=0), amax=jnp.max(masked_hi, axis=0),
         hist=hist, log_ref=jnp.asarray(log_ref, jnp.float32))
 
@@ -266,7 +267,9 @@ def wilson_bounds(p: np.ndarray, n: np.ndarray,
     denom = 1.0 + z2 / n
     center = (p + z2 / (2.0 * n)) / denom
     half = z * np.sqrt(p * (1.0 - p) / n + z2 / (4.0 * n * n)) / denom
-    return np.clip(center - half, 0.0, 1.0), np.clip(center + half, 0.0, 1.0)
+    # The interval holds p exactly; the clip to p only undoes rounding
+    # (at p = 1, center + half can land one ulp below 1).
+    return np.clip(center - half, 0.0, p), np.clip(center + half, p, 1.0)
 
 
 def clopper_pearson_bounds(p: np.ndarray, n: np.ndarray,
@@ -274,16 +277,11 @@ def clopper_pearson_bounds(p: np.ndarray, n: np.ndarray,
                            ) -> tuple[np.ndarray, np.ndarray]:
     """Exact (conservative) Clopper-Pearson interval via the beta quantile.
 
-    Needs ``scipy.stats.beta``; when scipy is unavailable the Wilson
-    interval is returned instead (a documented, slightly narrower
-    fallback — never a crash on a lean container).  Non-integer
-    ``k = p n`` (weighted streams) is supported: the beta quantile is
-    continuous in its shape parameters.
+    Non-integer ``k = p n`` (weighted streams) is supported: the beta
+    quantile is continuous in its shape parameters.
     """
-    try:
-        from scipy.stats import beta
-    except ImportError:  # pragma: no cover - container ships scipy
-        return wilson_bounds(p, n, confidence)
+    from scipy.stats import beta
+
     p = np.asarray(p, np.float64)
     n = np.maximum(np.asarray(n, np.float64), 1e-12)
     k = np.clip(p * n, 0.0, n)
@@ -298,40 +296,10 @@ def clopper_pearson_bounds(p: np.ndarray, n: np.ndarray,
 
 
 def _norm_ppf(q: float) -> float:
-    """Standard-normal quantile; scipy when present, else Acklam's
-    rational approximation (|err| < 1.2e-9 — far below CI tolerances)."""
-    try:
-        from scipy.stats import norm
-        return float(norm.ppf(q))
-    except ImportError:  # pragma: no cover - container ships scipy
-        return _acklam_ppf(q)
+    """Standard-normal quantile."""
+    from scipy.stats import norm
 
-
-def _acklam_ppf(q: float) -> float:  # pragma: no cover - scipy fallback
-    a = (-3.969683028665376e+01, 2.209460984245205e+02,
-         -2.759285104469687e+02, 1.383577518672690e+02,
-         -3.066479806614716e+01, 2.506628277459239e+00)
-    b = (-5.447609879822406e+01, 1.615858368580409e+02,
-         -1.556989798598866e+02, 6.680131188771972e+01,
-         -1.328068155288572e+01)
-    c = (-7.784894002430293e-03, -3.223964580411365e-01,
-         -2.400758277161838e+00, -2.549732539343734e+00,
-         4.374664141464968e+00, 2.938163982698783e+00)
-    d = (7.784695709041462e-03, 3.224671290700398e-01,
-         2.445134137142996e+00, 3.754408661907416e+00)
-    p_low, p_high = 0.02425, 1 - 0.02425
-    if q < p_low:
-        u = np.sqrt(-2 * np.log(q))
-        return (((((c[0] * u + c[1]) * u + c[2]) * u + c[3]) * u + c[4])
-                * u + c[5]) / ((((d[0] * u + d[1]) * u + d[2]) * u + d[3])
-                               * u + 1)
-    if q > p_high:
-        return -_acklam_ppf(1 - q)
-    u = q - 0.5
-    t = u * u
-    return (((((a[0] * t + a[1]) * t + a[2]) * t + a[3]) * t + a[4])
-            * t + a[5]) * u / (((((b[0] * t + b[1]) * t + b[2]) * t + b[3])
-                                * t + b[4]) * t + 1)
+    return float(norm.ppf(q))
 
 
 def hist_quantiles(hist: np.ndarray, qs) -> np.ndarray:
@@ -366,7 +334,7 @@ def finalize(state: StreamStats, confidence: float = DEFAULT_CONFIDENCE,
     binomial bounds at ``confidence``, over the *effective* sample size),
     ``count``, ``n_eff`` and the interval config.  ``ci`` selects
     ``'wilson'`` (closed-form score interval) or ``'clopper-pearson'``
-    (exact beta quantiles, scipy-gated).
+    (exact beta quantiles).
     """
     w = max(float(state.w_sum), 1e-300)
     w2 = max(float(state.w2_sum), 1e-300)
@@ -431,12 +399,6 @@ class QMCSampler:
             raise ValueError(
                 f"mismatch space has {dim} dims > Sobol table limit "
                 f"{SOBOL_MAX_DIM}; use method='stratified' or 'iid'")
-        try:
-            from scipy.stats import qmc  # noqa: F401
-        except ImportError as e:  # pragma: no cover - container has scipy
-            raise RuntimeError(
-                "QMC sampling needs scipy.stats.qmc; install scipy or use "
-                "method='iid'") from e
         self.method = method
         self.dim = int(dim)
         kd = np.asarray(key_data, np.uint32).ravel()

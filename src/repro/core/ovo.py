@@ -251,7 +251,8 @@ class DigitalRBFClassifier:
             xq.astype(jnp.float32), jnp.asarray(self.support_x, jnp.float32),
             jnp.float32(self.gamma),
         )
-        return np.asarray(k @ jnp.asarray(self.coef, jnp.float32)) + self.bias
+        coef = jnp.asarray(self.coef, jnp.float32)
+        return np.asarray(kern.matmul(k, coef)) + self.bias
 
     def predict_bits(self, x: np.ndarray) -> np.ndarray:
         return (self.decision(x) >= 0.0).astype(np.int32)

@@ -226,7 +226,7 @@ def _train_eval_masked(
     """Train on masked subset, return (alpha, val_acc on the complement)."""
     kp = kern.kernel_matrix(kind, x, x, gamma) + 1.0
     alpha = dual_coordinate_ascent(kp, y, c * train_mask, n_epochs)
-    f = kp @ (alpha * y)
+    f = kern.matmul(kp, alpha * y)
     pred = jnp.where(f >= 0.0, 1.0, -1.0)
     val = 1.0 - train_mask
     val_acc = jnp.sum((pred == y) * val) / jnp.clip(jnp.sum(val), 1.0, None)

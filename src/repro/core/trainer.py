@@ -285,7 +285,7 @@ def dual_coordinate_ascent_blocked(
         cb = jax.lax.dynamic_slice(c_box, (j0,), (block,))
         qb = jax.lax.dynamic_slice(qdiag, (j0,), (block,))
         ab = jax.lax.dynamic_slice(alpha, (j0,), (block,))
-        fb = rows @ (alpha * y)                # fresh block margins, one GEMM
+        fb = kern.matmul(rows, alpha * y)      # fresh block margins, one GEMM
 
         def coord(i, c2):
             ab, fb = c2
@@ -349,10 +349,17 @@ def _training_kernel(kind):
 
     def fast_hw_kernel(x, sv, gamma_star):
         s = hw.input_scale(gamma_star)
-        dv = hw.v_scale * s * (x[:, None, :] - sv[None, :, :]) + hw.mu
-        return jnp.prod(
-            kern._uniform_interp(dv, curve, lo, hi, left, right, inv_step),
-            axis=-1)
+        # Per-dimension accumulation, as in the compiled inference path:
+        # (n, m) temporaries instead of one (n, m, d) tensor, whose
+        # d-minor layout the TPU pads to 128 lanes (26x the bytes at
+        # d = 5).  Same sequential multiply order as jnp.prod.
+        acc = None
+        for k in range(x.shape[1]):
+            dv = hw.v_scale * s * (x[:, k:k + 1] - sv[None, :, k]) + hw.mu
+            k1 = kern._uniform_interp(dv, curve, lo, hi, left, right,
+                                      inv_step)
+            acc = k1 if acc is None else acc * k1
+        return acc
 
     while len(_HW_KERNEL_CACHE) >= _HW_KERNEL_CACHE_MAX:
         _HW_KERNEL_CACHE.pop(next(iter(_HW_KERNEL_CACHE)))
@@ -374,7 +381,7 @@ def _cell_cv_accuracy(kp, yp, mask, vp, c, n_epochs):
     Pallas solver's fused ``f`` output instead of a ``kp @ (alpha * y)``
     against a materialized Gram (DESIGN.md §7.1)."""
     alpha = dual_coordinate_ascent_blocked(kp, yp, c * mask * vp, n_epochs)
-    f = kp @ (alpha * yp)
+    f = kern.matmul(kp, alpha * yp)
     pred = jnp.where(f >= 0.0, 1.0, -1.0)
     val = (1.0 - mask) * vp
     return jnp.sum((pred == yp) * val) / jnp.clip(jnp.sum(val), 1.0, None)
@@ -598,7 +605,6 @@ def _cv_grid_sharded(padded, kind, gammas, cs, n_epochs, mesh,
     With ``use_pallas`` each shard's cells run through the fused solver
     lanes (P=cells, G=1) instead of the vmapped blocked solver.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     if PAIRGRID_AXIS not in mesh.axis_names:
@@ -642,10 +648,10 @@ def _cv_grid_sharded(padded, kind, gammas, cs, n_epochs, mesh,
         return jax.vmap(cell)(xs, ys, fs, vs, gs)
 
     sharded = P(PAIRGRID_AXIS)
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         local, mesh=mesh,
         in_specs=(sharded, sharded, sharded, sharded, sharded, P()),
-        out_specs=sharded, check_rep=False))
+        out_specs=sharded, check_vma=False))
     out = fn(jnp.asarray(xg), jnp.asarray(yg), jnp.asarray(fmg),
              jnp.asarray(vg), jnp.asarray(gg),
              jnp.asarray(cs, jnp.float32))

@@ -22,12 +22,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.core.kernels import PRECISION
 
 
 def linear_tile(x: jnp.ndarray, z: jnp.ndarray, gamma=None) -> jnp.ndarray:
     """One (bm, bn) linear-kernel tile x @ z.T on the MXU; gamma ignored."""
     return jax.lax.dot_general(
-        x, z, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, z, (((1,), (1,)), ((), ())), precision=PRECISION,
+        preferred_element_type=jnp.float32
     )
 
 
@@ -82,7 +86,7 @@ def _rbf_kernel(x_ref, z_ref, g_ref, o_ref):
     """One (bm, bn) tile: distance via MXU matmul + fused exp."""
     x = x_ref[...].astype(jnp.float32)          # (bm, d)
     z = z_ref[...].astype(jnp.float32)          # (bn, d)
-    o_ref[...] = rbf_tile(x, z, g_ref[0]).astype(o_ref.dtype)
+    o_ref[...] = rbf_tile(x, z, g_ref[0, 0]).astype(o_ref.dtype)
 
 
 def _sech2_kernel(x_ref, z_ref, g_ref, o_ref, *,
@@ -90,7 +94,7 @@ def _sech2_kernel(x_ref, z_ref, g_ref, o_ref, *,
     """One (bm, bn) tile of the hardware kernel: log-space product (Eq. 6)."""
     x = x_ref[...].astype(jnp.float32)
     z = z_ref[...].astype(jnp.float32)
-    o_ref[...] = sech2_tile(x, z, g_ref[0], n_slope=n_slope, v_t=v_t,
+    o_ref[...] = sech2_tile(x, z, g_ref[0, 0], n_slope=n_slope, v_t=v_t,
                             v_scale=v_scale).astype(o_ref.dtype)
 
 
@@ -124,7 +128,7 @@ def kernel_matrix_pallas(
     m = z.shape[0]
     xp = _pad_to(x, bm, 0)
     zp = _pad_to(z, bn, 0)
-    g = jnp.reshape(jnp.asarray(gamma, jnp.float32), (1,))
+    g = jnp.reshape(jnp.asarray(gamma, jnp.float32), (1, 1))
     grid = (xp.shape[0] // bm, zp.shape[0] // bn)
 
     if kind == "rbf":
@@ -142,7 +146,7 @@ def kernel_matrix_pallas(
         in_specs=[
             pl.BlockSpec((bm, d), lambda i, j: (i, 0)),
             pl.BlockSpec((bn, d), lambda i, j: (j, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),   # gamma: tiny, whole array
+            pl.BlockSpec(memory_space=pltpu.SMEM),   # gamma: a (1, 1) scalar
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((xp.shape[0], zp.shape[0]), jnp.float32),

@@ -10,6 +10,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core.kernels import PRECISION
+
 
 # ---------------------------------------------------------------------------
 # RBF kernel matrix (the paper's hot loop)
@@ -21,7 +23,7 @@ def rbf_matrix(x: jnp.ndarray, z: jnp.ndarray, gamma: float) -> jnp.ndarray:
     d2 = (
         jnp.sum(x * x, -1)[:, None]
         + jnp.sum(z * z, -1)[None, :]
-        - 2.0 * (x @ z.T)
+        - 2.0 * jnp.matmul(x, z.T, precision=PRECISION)
     )
     return jnp.exp(-gamma * jnp.maximum(d2, 0.0))
 
@@ -78,7 +80,7 @@ def dual_ascent_blocked(
         cb = jax.lax.dynamic_slice(c_box, (j0,), (block,))
         qb = jax.lax.dynamic_slice(qdiag, (j0,), (block,))
         ab = jax.lax.dynamic_slice(alpha, (j0,), (block,))
-        fb = rows @ (alpha * y)
+        fb = jnp.matmul(rows, alpha * y, precision=PRECISION)
 
         def coord(i, carry):
             ab, fb = carry
@@ -96,7 +98,7 @@ def dual_ascent_blocked(
 
     alpha = jax.lax.fori_loop(0, n_epochs, epoch,
                               jnp.zeros((n_pad,), kp.dtype))
-    f = kp @ (alpha * y)
+    f = jnp.matmul(kp, alpha * y, precision=PRECISION)
     return alpha[:n], f[:n]
 
 
@@ -121,7 +123,7 @@ def solve_lanes(
 
     def kmat(xp, g):
         if kind == "linear":
-            k = xp @ xp.T
+            k = jnp.matmul(xp, xp.T, precision=PRECISION)
         elif kind == "rbf":
             k = rbf_matrix(xp, xp, g)
         elif kind == "sech2":

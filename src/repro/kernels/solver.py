@@ -31,8 +31,13 @@ Lane layout
 ``x (P, n, d)`` / ``y (P, n)`` are per-*pair*; ``gamma (P, G)`` spans the
 width grid; ``c_box (P, L, n)`` spans the gamma-independent C x fold
 lanes (the box already folds the train-mask and validity in).  The grid
-is ``(P, G, L)`` — row-major iteration revisits the same pair block for
-all its (G, L) lanes, so Pallas's pipelining keeps the pair inputs hot.
+is ``(P, G)``: one program per (pair, gamma) solves all L lanes at once,
+since they share every Gram slab.  Inside it samples run along sublanes
+and lanes along the vector lanes — ``c`` and ``alpha`` are (n, L) — so
+each coordinate block is an aligned (block, L) sublane slab and every
+block operand meets the TPU's (8, 128) tiling as a full trailing
+dimension; gamma is read from SMEM.  Row-major iteration revisits the
+same pair block for all its G programs, so the pair inputs stay hot.
 Outputs are ``alpha (P, G, L, n)`` and the final margins ``f (P, G, L,
 n)`` (``f_j = sum_i K'_ji alpha_i y_i``), computed by one extra fused
 pass over the row slabs so CV validation never needs the Gram either.
@@ -44,78 +49,89 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.kernels import PRECISION
 from repro.kernels.rbf import tile_body
 
 #: Coordinate-block size; matches ``repro.core.trainer.SOLVER_BLOCK``.
 DEFAULT_BLOCK = 16
 
 
-def _solver_kernel(x_ref, y_ref, c_ref, g_ref, alpha_ref, f_ref, *,
+def _row(v, hit):
+    """Row ``i`` of a (block, k) array as (1, k), selected by the one-hot
+    sublane mask ``hit``: one nonzero term per sum, so the pick is exact."""
+    return jnp.sum(jnp.where(hit, v, 0.0), axis=0, keepdims=True)
+
+
+def _solver_kernel(g_ref, x_ref, y_ref, c_ref, alpha_ref, f_ref, *,
                    block: int, n_epochs: int, tile):
-    """One lane: full dual-coordinate-ascent epoch loop, VMEM-resident."""
-    x = x_ref[0]                      # (n_pad, d)
-    yv = y_ref[...]                   # (1, n_pad)
-    cv = c_ref[0]                     # (1, n_pad)
-    gamma = g_ref[0, 0]
-    n_pad, d = x.shape
+    """One (pair, gamma): every C x fold lane of it, VMEM-resident.
+
+    Samples run along sublanes and lanes along the vector lanes, so each
+    coordinate block is an aligned (block, L) sublane slab and the
+    Gauss-Seidel step is one masked row update shared by all L lanes.
+    """
+    gamma = g_ref[pl.program_id(0), pl.program_id(1)]
+    yv = y_ref[0]                     # (n_pad, 1)
+    n_pad = yv.shape[0]
     n_blocks = n_pad // block
+    sub = jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
 
-    def rows_at(j0):
-        """Fresh (block, n_pad) Gram row slab K'[j0:j0+block, :] + bias."""
-        xb = jax.lax.dynamic_slice(x, (j0, 0), (block, d))
-        return tile(xb, x, gamma) + 1.0          # bias-as-feature
+    def slab(j0):
+        """Rows j0:j0+block of x, and the fresh Gram row slab
+        K'[j0:j0+block, :] + bias with its (block, block) diagonal tile."""
+        xb = x_ref[0, pl.ds(j0, block), :]
+        rows = tile(xb, x_ref[0], gamma) + 1.0       # bias-as-feature
+        return rows, tile(xb, xb, gamma) + 1.0
 
-    def block_body(b, alpha):
-        j0 = b * block
-        rows = rows_at(j0)
-        kbb = jax.lax.dynamic_slice(rows, (0, j0), (block, block))
-        yb = jax.lax.dynamic_slice(yv, (0, j0), (1, block))
-        cb = jax.lax.dynamic_slice(cv, (0, j0), (1, block))
+    def margins(rows):
+        """(block, L) margins rows @ (alpha * y) from the current alphas."""
+        return jax.lax.dot_general(
+            rows, alpha_ref[0, 0] * yv, (((1,), (0,)), ((), ())),
+            precision=PRECISION, preferred_element_type=jnp.float32)
+
+    def block_body(b, carry):
+        j0 = pl.multiple_of(b * block, block)
+        rows, kbb = slab(j0)
         # The oracle's qdiag values: K'(x_i, x_i), same tile math.
-        qb = jnp.clip(jnp.diagonal(kbb), 1e-12, None)
-        ab = jax.lax.dynamic_slice(alpha, (0, j0), (1, block))
-        # Fresh block margins from the current alphas: ONE (1, n) x
-        # (block, n)^T contraction — the blocked oracle's `rows @ (a*y)`.
-        fb = jax.lax.dot_general(
-            alpha * yv, rows, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)  # (1, block)
+        qb = jnp.clip(jnp.sum(jnp.where(sub == lane, kbb, 0.0), axis=1,
+                              keepdims=True), 1e-12, None)  # (block, 1)
+        yb = y_ref[0, pl.ds(j0, block), :]             # (block, 1)
+        cb = c_ref[0, pl.ds(j0, block), :]             # (block, L)
+        ab = alpha_ref[0, 0, pl.ds(j0, block), :]      # (block, L)
+        fb = margins(rows)                             # (block, L)
 
         def coord(i, carry):
             ab, fb = carry
-            g = 1.0 - yb[0, i] * fb[0, i]
-            a_new = jnp.clip(ab[0, i] + g / qb[i], 0.0, cb[0, i])
-            dlt = a_new - ab[0, i]
-            col = jax.lax.dynamic_slice(kbb, (0, i), (block, 1))
-            fb = fb + dlt * yb[0, i] * col.reshape(1, block)
-            ab = jax.lax.dynamic_update_slice(
-                ab, a_new.reshape(1, 1), (0, i))
-            return ab, fb
+            hit = sub == i
+            y_i, q_i = _row(yb, hit), _row(qb, hit)
+            a_i = _row(ab, hit)
+            g = 1.0 - y_i * _row(fb, hit)
+            a_new = jnp.clip(a_i + g / q_i, 0.0, _row(cb, hit))
+            dlt = a_new - a_i
+            col = jnp.sum(jnp.where(lane == i, kbb, 0.0), axis=1,
+                          keepdims=True)               # kbb[:, i]
+            return jnp.where(hit, a_new, ab), fb + dlt * y_i * col
 
         ab, _ = jax.lax.fori_loop(0, block, coord, (ab, fb))
-        return jax.lax.dynamic_update_slice(alpha, ab, (0, j0))
+        alpha_ref[0, 0, pl.ds(j0, block), :] = ab
+        return carry
 
-    def epoch(_, alpha):
-        return jax.lax.fori_loop(0, n_blocks, block_body, alpha)
-
-    alpha = jax.lax.fori_loop(0, n_epochs, epoch,
-                              jnp.zeros((1, n_pad), jnp.float32))
+    alpha_ref[...] = jnp.zeros(alpha_ref.shape, alpha_ref.dtype)
+    for_blocks = functools.partial(jax.lax.fori_loop, 0, n_blocks)
+    jax.lax.fori_loop(0, n_epochs,
+                      lambda _, c: for_blocks(block_body, c), 0)
 
     # Final margins f = K' @ (alpha * y), one more fused pass over the
     # row slabs — CV validation consumes f directly, Gram-free.
-    ay = alpha * yv
+    def final_block(b, carry):
+        j0 = pl.multiple_of(b * block, block)
+        f_ref[0, 0, pl.ds(j0, block), :] = margins(slab(j0)[0])
+        return carry
 
-    def final_block(b, f):
-        j0 = b * block
-        fb = jax.lax.dot_general(
-            ay, rows_at(j0), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return jax.lax.dynamic_update_slice(f, fb, (0, j0))
-
-    f = jax.lax.fori_loop(0, n_blocks, final_block,
-                          jnp.zeros((1, n_pad), jnp.float32))
-    alpha_ref[...] = alpha.reshape(alpha_ref.shape)
-    f_ref[...] = f.reshape(f_ref.shape)
+    for_blocks(final_block, 0)
 
 
 @functools.partial(
@@ -145,6 +161,9 @@ def dual_ascent_lanes_pallas(
     g_count = gamma.shape[1]
     l_count = c_box.shape[1]
     blk = int(min(block, n))
+    # Sublane slabs start at multiples of the block: keep it a multiple of
+    # 8 (the f32 sublane tile) so every slab is aligned.
+    blk = -(-blk // 8) * 8
     n_pad = -(-n // blk) * blk
     if n_pad != n:
         # Padding rows are inert: zero box ==> alpha frozen at 0 ==> they
@@ -155,22 +174,24 @@ def dual_ascent_lanes_pallas(
     tile = tile_body(kind, n_slope=n_slope, v_t=v_t, v_scale=v_scale)
     body = functools.partial(_solver_kernel, block=blk,
                              n_epochs=int(n_epochs), tile=tile)
-    out_shape = jax.ShapeDtypeStruct((p, g_count, l_count, n_pad),
+    out_shape = jax.ShapeDtypeStruct((p, g_count, n_pad, l_count),
                                      jnp.float32)
+    out_spec = pl.BlockSpec((1, 1, n_pad, l_count),
+                            lambda i, j: (i, j, 0, 0))
     alpha, f = pl.pallas_call(
         body,
-        grid=(p, g_count, l_count),
+        grid=(p, g_count),
         in_specs=[
-            pl.BlockSpec((1, n_pad, d), lambda i, j, k: (i, 0, 0)),
-            pl.BlockSpec((1, n_pad), lambda i, j, k: (i, 0)),
-            pl.BlockSpec((1, 1, n_pad), lambda i, j, k: (i, k, 0)),
-            pl.BlockSpec((1, 1), lambda i, j, k: (i, j)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),     # gamma (P, G)
+            pl.BlockSpec((1, n_pad, d), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, n_pad, 1), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, n_pad, l_count), lambda i, j: (i, 0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, n_pad), lambda i, j, k: (i, j, k, 0)),
-            pl.BlockSpec((1, 1, 1, n_pad), lambda i, j, k: (i, j, k, 0)),
-        ],
+        out_specs=[out_spec, out_spec],
         out_shape=[out_shape, out_shape],
         interpret=interpret,
-    )(x, y, c_box, jnp.asarray(gamma, jnp.float32))
-    return alpha[..., :n], f[..., :n]
+    )(jnp.asarray(gamma, jnp.float32), x, y[..., None],
+      jnp.swapaxes(c_box, 1, 2))
+    alpha = jnp.swapaxes(alpha, 2, 3)[..., :n]
+    f = jnp.swapaxes(f, 2, 3)[..., :n]
+    return alpha, f

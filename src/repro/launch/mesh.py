@@ -2,23 +2,15 @@
 
 Defined as FUNCTIONS so importing this module never touches jax device
 state (the dry-run must set XLA_FLAGS before any jax initialisation).
-
-``jax.sharding.AxisType`` only exists on newer jax; older builds construct
-the same mesh without explicit axis types (Auto is their only behavior).
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-try:
-    from jax.sharding import AxisType
 
-    def _axis_kwargs(n_axes: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n_axes}
-except ImportError:  # pre-AxisType jax: meshes are implicitly Auto
-
-    def _axis_kwargs(n_axes: int) -> dict:
-        return {}
+def _axis_kwargs(n_axes: int) -> dict:
+    return {"axis_types": (AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

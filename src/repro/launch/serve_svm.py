@@ -60,6 +60,9 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from repro.api import MixedKernelSVM, compile_fleet
     from repro.data import datasets
     from repro.serving import ShedError, SVMEngine

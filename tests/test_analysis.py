@@ -217,7 +217,7 @@ def host_bench(x):
 
 
 def test_f64_leak_fires():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(
             lambda x: x * np.float64(2.0))(jnp.zeros(3, jnp.float32))
     findings, _ = jaxpr_checks.check_jaxpr(closed, path="fixture",

@@ -13,17 +13,6 @@ from repro.distributed import partition
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
-try:
-    from jax.sharding import AxisType  # noqa: F401  (feature probe)
-
-    _HAS_AXIS_TYPE = True
-except ImportError:  # older jax: explicit-axis-type mesh API not available
-    _HAS_AXIS_TYPE = False
-
-needs_axis_types = pytest.mark.skipif(
-    not _HAS_AXIS_TYPE,
-    reason="jax.sharding.AxisType / jax.set_mesh unavailable on this jax")
-
 
 def run_multidevice(body: str):
     """Run `body` in a fresh python with 8 fake devices."""
@@ -81,7 +70,6 @@ def test_param_specs_cover_every_leaf():
 # -- 8-device shard_map behaviours -------------------------------------------
 
 
-@needs_axis_types
 def test_compressed_allreduce_mean_and_feedback():
     run_multidevice("""
         from jax.sharding import AxisType
@@ -110,7 +98,6 @@ def test_compressed_allreduce_mean_and_feedback():
     """)
 
 
-@needs_axis_types
 def test_pipeline_matches_sequential():
     run_multidevice("""
         from jax.sharding import AxisType
@@ -135,7 +122,6 @@ def test_pipeline_matches_sequential():
     """)
 
 
-@needs_axis_types
 def test_elastic_reshard_roundtrip():
     run_multidevice("""
         from jax.sharding import AxisType, NamedSharding
@@ -158,7 +144,6 @@ def test_elastic_reshard_roundtrip():
     """)
 
 
-@needs_axis_types
 def test_small_mesh_train_step_shards():
     """A reduced model train step under a (2, 4) mesh with real
     in_shardings — the miniature of the production dry-run."""

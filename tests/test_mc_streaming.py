@@ -140,6 +140,28 @@ def test_chunk_size_invariance(tiny):
         np.testing.assert_allclose(out["hist"], outs[0]["hist"])
 
 
+@pytest.mark.parametrize("batch", [1, 3])
+def test_variant_slices_match_one_pass(tiny, monkeypatch, batch):
+    """Past ``MC_LANE_BYTES`` the analog lanes take a chunk's variants in
+    sequential slices (``_variant_batch``; 3 leaves a ragged last slice
+    of 8): the bits and the stream equal those of one pass."""
+    cands, x, y, a = tiny
+
+    def run():
+        sm = api.compile_mc_stream(cands, n_classes=3,
+                                   key=jax.random.PRNGKey(0), mc_chunk=8)
+        return (sm.pair_bits_dense(x, np.arange(8)),
+                sm.stream(x, y, a, n_variants=21, accuracy_floor=0.6))
+
+    bits, out = run()
+    monkeypatch.setattr(api, "_variant_batch", lambda *shape: batch)
+    bits_b, out_b = run()
+    np.testing.assert_array_equal(bits_b, bits)
+    assert abs(out_b["mean"][0] - out["mean"][0]) < MOMENT_TOL
+    assert out_b["worst"][0] == out["worst"][0]
+    assert out_b["yield"][0] == out["yield"][0]
+
+
 def test_stream_rejects_bad_config(tiny):
     cands, x, y, a = tiny
     with pytest.raises(ValueError, match="method"):
@@ -273,6 +295,16 @@ def test_wilson_and_clopper_pearson_bounds():
     clo1, chi1 = mcstream.clopper_pearson_bounds(1.0, 64)
     assert chi1 == 1.0 and clo1 == pytest.approx(0.025 ** (1 / 64),
                                                  abs=1e-4)
+
+
+@pytest.mark.parametrize("n", [64, 256, 1000, 16384])
+def test_wilson_bounds_hold_the_point_estimate(n):
+    """lo <= p <= hi exactly, also where rounding would put the bound of
+    p = 1 one ulp below 1 (n = 256)."""
+    p = np.array([0.0, 0.3, 1.0])
+    lo, hi = mcstream.wilson_bounds(p, n)
+    assert np.all(lo <= p) and np.all(p <= hi)
+    assert lo[0] == 0.0 and hi[2] == 1.0
 
 
 def test_hist_quantiles_exact_on_grid():

@@ -671,6 +671,23 @@ def test_sharded_fleet_forward_bit_identity_subprocess():
     assert "OK" in res.stdout
 
 
+def test_sharded_forward_with_pallas_banks():
+    """The mesh forward over Pallas rbf banks (``use_pallas``, the TPU
+    default): ``shard_map`` accepts the kernel's outputs and the labels
+    equal the unsharded forward (interpret mode, one-device mesh)."""
+    from repro.launch.mesh import make_serving_mesh
+
+    fleet = compile_fleet({"tiny": tiny_machine(0),
+                           "wide": tiny_machine(1, d=5, m=8, n_classes=4)},
+                          use_pallas=True, interpret=True)
+    fwd = fleet.shard(make_serving_mesh(1))
+    gen = np.random.default_rng(3)
+    x = fleet._pad_features(_queries(gen, 16, fleet.n_features))
+    idx = fleet._resolve_idx([fleet.model_ids[i % 2] for i in range(16)], 16)
+    np.testing.assert_array_equal(np.asarray(fwd(x, idx.copy())),
+                                  np.asarray(fleet._labels_jit(x, idx.copy())))
+
+
 def test_serving_mesh_requires_batch_axis(engine_fleet):
     from repro.launch import mesh as mesh_mod
 
