@@ -19,7 +19,10 @@ Subsystems:
   repro.kernels      Pallas TPU kernels (+ refs)
   repro.configs      architecture configs
   repro.launch       mesh / dryrun / train / serve entrypoints
+  repro.obs          compile counters (jax.monitoring), registered on import
 """
+
+from repro import obs  # noqa: F401  (registers the compile counters)
 
 __version__ = "1.1.0"
 
@@ -27,7 +30,7 @@ _API_EXPORTS = ("MixedKernelSVM", "CompiledMachine", "compile_machine")
 
 
 def __getattr__(name):
-    """Lazy re-export of the public API (keeps `import repro` dependency-free)."""
+    """Lazy re-export of the public API (keeps `import repro` light)."""
     if name in _API_EXPORTS:
         from repro import api
 

@@ -1287,6 +1287,11 @@ def _all_scores_mc(x: jnp.ndarray, linear_banks, kernel_banks,
     Variation-free lanes (linear-digital, digital-RBF) are evaluated ONCE
     and broadcast over the variant axis; only banks with attached
     ``_BankVariants`` vmap over it.
+
+    Device scopes (``jax.named_scope``, metadata only): ``mc.lanes.linear``,
+    ``mc.lanes.rbf`` (variation-free kernel banks) and ``mc.lanes.hw``
+    (variant lanes, and the merge of all columns onto their ``(V, n, C)``
+    grid).
     """
     xq_cache: dict[int, jnp.ndarray] = {}
 
@@ -1297,18 +1302,23 @@ def _all_scores_mc(x: jnp.ndarray, linear_banks, kernel_banks,
 
     cols = []
     for bank in linear_banks:
-        c = kern.matmul(xq(bank.input_bits), bank.w.T) + bank.b[None, :]
-        cols.append(jnp.broadcast_to(c[None], (n_variants,) + c.shape))
+        with jax.named_scope("mc.lanes.linear"):
+            c = kern.matmul(xq(bank.input_bits), bank.w.T) + bank.b[None, :]
+            cols.append(jnp.broadcast_to(c[None], (n_variants,) + c.shape))
     for bank, bv in zip(kernel_banks, bank_variants):
         if bv is None:
-            c = _bank_scores(bank, xq(bank.input_bits), use_pallas,
-                             interpret=interpret)
-            cols.append(jnp.broadcast_to(c[None], (n_variants,) + c.shape))
+            with jax.named_scope("mc.lanes.rbf"):
+                c = _bank_scores(bank, xq(bank.input_bits), use_pallas,
+                                 interpret=interpret)
+                cols.append(
+                    jnp.broadcast_to(c[None], (n_variants,) + c.shape))
         else:
-            cols.append(_bank_scores_mc(bank, bv, xq(bank.input_bits),
-                                        use_pallas, include_nominal,
-                                        interpret=interpret))
-    return jnp.concatenate(cols, axis=2)[:, :, inv_perm]
+            with jax.named_scope("mc.lanes.hw"):
+                cols.append(_bank_scores_mc(bank, bv, xq(bank.input_bits),
+                                            use_pallas, include_nominal,
+                                            interpret=interpret))
+    with jax.named_scope("mc.lanes.hw"):
+        return jnp.concatenate(cols, axis=2)[:, :, inv_perm]
 
 
 class MonteCarloMachine:
@@ -1564,6 +1574,13 @@ class StreamingMCMachine:
     sample size the confidence bounds use).  ``stream(mesh=)`` shards
     the chunk's variant axis across a ``launch.mesh.make_variant_mesh``
     with one psum/pmin/pmax merge per chunk.
+
+    Measurement: the step's device ops carry the named scopes ``mc.draw``,
+    ``mc.lanes.linear``/``.rbf``/``.hw``, ``mc.recombine`` and ``mc.fold``;
+    :meth:`stream` marks its host phases with profiler spans
+    (``repro.mc.prep``, ``.dispatch``, ``.wait``, ``.finalize``, which
+    record nothing unless a profiler is tracing) and keeps the work
+    totals ``steps_run``, ``variants_run`` and ``lane_cells_run``.
     """
 
     def __init__(self, n_classes: int, linear_banks, kernel_banks,
@@ -1614,6 +1631,17 @@ class StreamingMCMachine:
         self._step_jit = jax.jit(self._step, donate_argnums=(0,))
         self._bits_jit = jax.jit(self._bits)
         self._sharded_steps: dict = {}
+        #: Padded ``(Pb, M)`` slots of the variant banks: the analog lane
+        #: cells one variant-row computes.
+        self._variant_slots = sum(
+            int(bank.sv.shape[0] * bank.sv.shape[1])
+            for bank, bc in zip(kernel_banks, stream_consts) if bc is not None)
+        #: Work :meth:`stream` dispatched over the machine's life: chunk
+        #: steps, valid variants, and analog lane cells computed (every
+        #: step's chunk x rows x ``_variant_slots``).
+        self.steps_run = 0
+        self.variants_run = 0
+        self.lane_cells_run = 0
 
     # -- in-graph chunk generation ------------------------------------------
 
@@ -1708,20 +1736,14 @@ class StreamingMCMachine:
 
     def _chunk_acc(self, x, v_idx, assignments, y, u):
         """One chunk end to end: draws -> scores -> bits -> acc (B, S)."""
-        bank_variants, w, log_ref = self._chunk_banks(v_idx, u)
-        flat = _all_scores_mc(
-            x, self._linear_banks, self._kernel_banks, bank_variants,
-            self._inv_perm, int(v_idx.shape[0]), False, self.use_pallas,
-            interpret=self.interpret)                       # (B, n, 2P)
-        scores = jnp.stack(
-            [flat[..., : self.n_pairs], flat[..., self.n_pairs:]], axis=-1)
-        bits = (scores >= 0.0).astype(jnp.int32)            # (B, n, P, 2)
-        if self._table is not None:
-            acc = _recombine_acc(bits, assignments, y, self._table,
-                                 self._weights)
-        else:
-            acc = dse._votes_accuracy_paired(
-                bits, assignments, y, self._vote_a, self._vote_b)
+        bits, w, log_ref = self._bits(x, v_idx, u)
+        with jax.named_scope("mc.recombine"):
+            if self._table is not None:
+                acc = _recombine_acc(bits, assignments, y, self._table,
+                                     self._weights)
+            else:
+                acc = dse._votes_accuracy_paired(
+                    bits, assignments, y, self._vote_a, self._vote_b)
         return acc, w, log_ref, bits
 
     def _step(self, state, x, v_idx, valid, floor, assignments, y, u):
@@ -1729,20 +1751,25 @@ class StreamingMCMachine:
         ``mc_chunk`` and the assignment matrix only — one compile per
         machine regardless of the total variant count."""
         acc, w, log_ref, _ = self._chunk_acc(x, v_idx, assignments, y, u)
-        return mcstream.update_stream(state, acc, w, valid, floor,
-                                      log_ref=log_ref)
+        with jax.named_scope("mc.fold"):
+            return mcstream.update_stream(state, acc, w, valid, floor,
+                                          log_ref=log_ref)
 
     def _bits(self, x, v_idx, u):
-        """Chunk bits oracle (un-donated): ``(B, n, P, 2)`` + weights
-        relative to the chunk's own log-scale (also returned)."""
-        bank_variants, w, log_ref = self._chunk_banks(v_idx, u)
+        """Chunk bits ``(B, n, P, 2)`` + weights relative to the chunk's
+        own log-scale (also returned): the step's draws and lanes, and
+        un-donated under ``_bits_jit``, the dense oracle."""
+        with jax.named_scope("mc.draw"):
+            bank_variants, w, log_ref = self._chunk_banks(v_idx, u)
         flat = _all_scores_mc(
             x, self._linear_banks, self._kernel_banks, bank_variants,
             self._inv_perm, int(v_idx.shape[0]), False, self.use_pallas,
-            interpret=self.interpret)
-        scores = jnp.stack(
-            [flat[..., : self.n_pairs], flat[..., self.n_pairs:]], axis=-1)
-        return (scores >= 0.0).astype(jnp.int32), w, log_ref
+            interpret=self.interpret)                       # (B, n, 2P)
+        with jax.named_scope("mc.lanes.hw"):
+            scores = jnp.stack(
+                [flat[..., : self.n_pairs], flat[..., self.n_pairs:]],
+                axis=-1)
+            return (scores >= 0.0).astype(jnp.int32), w, log_ref
 
     # -- sharded step (variant axis over a mesh) -----------------------------
 
@@ -1767,25 +1794,26 @@ class StreamingMCMachine:
             # literal 1.0 on every device whenever the scales agree —
             # always, for non-IS methods — so those sums stay bit-exact).
             acc, w, log_ref, _ = self._chunk_acc(x, v_idx, assignments, y, u)
-            agg = mcstream.chunk_aggregates(
-                state.mean, acc, w, valid, floor, state.hist.shape[1],
-                log_ref=log_ref)
-            ax = VARIANTS_AXIS
-            ref = jax.lax.pmax(agg.log_ref, ax)
-            f = jnp.where(agg.log_ref == ref, jnp.float32(1.0),
-                          jnp.exp(agg.log_ref - ref))
-            agg = mcstream.ChunkAgg(
-                n_c=jax.lax.psum(agg.n_c, ax),
-                w_c=jax.lax.psum(f * agg.w_c, ax),
-                w2_c=jax.lax.psum(f * f * agg.w2_c, ax),
-                s1=jax.lax.psum(f * agg.s1, ax),
-                s2=jax.lax.psum(f * agg.s2, ax),
-                exceed=jax.lax.psum(f * agg.exceed, ax),
-                amin=jax.lax.pmin(agg.amin, ax),
-                amax=jax.lax.pmax(agg.amax, ax),
-                hist=jax.lax.psum(f * agg.hist, ax),
-                log_ref=ref)
-            return mcstream.merge_stream(state, agg)
+            with jax.named_scope("mc.fold"):
+                agg = mcstream.chunk_aggregates(
+                    state.mean, acc, w, valid, floor, state.hist.shape[1],
+                    log_ref=log_ref)
+                ax = VARIANTS_AXIS
+                ref = jax.lax.pmax(agg.log_ref, ax)
+                f = jnp.where(agg.log_ref == ref, jnp.float32(1.0),
+                              jnp.exp(agg.log_ref - ref))
+                agg = mcstream.ChunkAgg(
+                    n_c=jax.lax.psum(agg.n_c, ax),
+                    w_c=jax.lax.psum(f * agg.w_c, ax),
+                    w2_c=jax.lax.psum(f * f * agg.w2_c, ax),
+                    s1=jax.lax.psum(f * agg.s1, ax),
+                    s2=jax.lax.psum(f * agg.s2, ax),
+                    exceed=jax.lax.psum(f * agg.exceed, ax),
+                    amin=jax.lax.pmin(agg.amin, ax),
+                    amax=jax.lax.pmax(agg.amax, ax),
+                    hist=jax.lax.psum(f * agg.hist, ax),
+                    log_ref=ref)
+                return mcstream.merge_stream(state, agg)
 
         fn = jax.shard_map(
             local_step, mesh=mesh,
@@ -1847,17 +1875,27 @@ class StreamingMCMachine:
         """
         if n_variants < 1:
             raise ValueError(f"n_variants must be >= 1, got {n_variants}")
-        x, y, a = self._prep(x, y, assignments)
-        b = self._chunk_size(mesh)
-        step = self._step_jit if mesh is None else self._sharded_step(mesh)
-        floor = jnp.float32(accuracy_floor)
-        state = mcstream.init_stream(
-            int(a.shape[0]), mcstream.hist_bins(int(x.shape[0])))
-        for start in range(0, n_variants, b):
-            v_idx, valid, u = self._chunk_inputs(start, b, n_variants)
-            state = step(state, x, v_idx, valid, floor, a, y, u)
-        out = mcstream.finalize(state, confidence, ci)
-        out["hist"] = np.asarray(state.hist, np.float64)
+        with jax.profiler.TraceAnnotation("repro.mc.prep"):
+            x, y, a = self._prep(x, y, assignments)
+            b = self._chunk_size(mesh)
+            step = (self._step_jit if mesh is None
+                    else self._sharded_step(mesh))
+            floor = jnp.float32(accuracy_floor)
+            state = mcstream.init_stream(
+                int(a.shape[0]), mcstream.hist_bins(int(x.shape[0])))
+        cells = b * int(x.shape[0]) * self._variant_slots
+        with jax.profiler.TraceAnnotation("repro.mc.dispatch"):
+            for start in range(0, n_variants, b):
+                v_idx, valid, u = self._chunk_inputs(start, b, n_variants)
+                state = step(state, x, v_idx, valid, floor, a, y, u)
+                self.steps_run += 1
+                self.variants_run += min(b, n_variants - start)
+                self.lane_cells_run += cells
+        with jax.profiler.TraceAnnotation("repro.mc.wait"):
+            state = jax.block_until_ready(state)
+        with jax.profiler.TraceAnnotation("repro.mc.finalize"):
+            out = mcstream.finalize(state, confidence, ci)
+            out["hist"] = np.asarray(state.hist, np.float64)
         out["n_variants"] = int(n_variants)
         out["method"] = self.method
         out["accuracy_floor"] = float(accuracy_floor)
@@ -1905,6 +1943,15 @@ class StreamingMCMachine:
         """XLA ``memory_analysis`` of the compiled chunk step at the given
         validation/assignment shapes — the object the flat-memory CI gate
         inspects.  Returns None when the backend does not report one."""
+        return self.compiled_step(n_val, n_assignments,
+                                  mesh).memory_analysis()
+
+    def compiled_step(self, n_val: int, n_assignments: int = 1, mesh=None):
+        """The chunk step compiled at the given validation/assignment
+        shapes.  Its ``as_text()`` names each device op and carries the
+        op's scope (``mc.draw``, ``mc.lanes.*``, ...) in the HLO metadata
+        ``op_name``, which a profiler trace's op events on the TPU do
+        not."""
         b = self._chunk_size(mesh)
         x = jnp.zeros((int(n_val), self.n_features), jnp.float32)
         y = jnp.zeros((int(n_val),), jnp.int32)
@@ -1913,9 +1960,8 @@ class StreamingMCMachine:
             int(n_assignments), mcstream.hist_bins(int(n_val)))
         v_idx, valid, u = self._chunk_inputs(0, b, b)
         step = self._step_jit if mesh is None else self._sharded_step(mesh)
-        lowered = step.lower(state, x, v_idx, valid,
-                             jnp.float32(0.5), a, y, u)
-        return lowered.compile().memory_analysis()
+        return step.lower(state, x, v_idx, valid, jnp.float32(0.5), a, y,
+                          u).compile()
 
 
 def compile_mc_stream(

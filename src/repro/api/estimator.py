@@ -593,17 +593,26 @@ class MixedKernelSVM:
                 sigma_scale=float(sigma_scale),
                 key_data=np.asarray(machine.key_data).tolist())
         else:
-            if accuracy_floor is None:
-                accuracy_floor = self.score(x, y, target="circuit") - 0.02
-            sm = self.stream_machine(
-                key, method=method or "iid",
-                mc_chunk=MC_STREAM_CHUNK if mc_chunk is None else mc_chunk,
-                sigma_scale=sigma_scale, is_scale=is_scale)
-        a = dse_mod.assignment_from_kernel_map(kmap)
-        out = sm.stream(np.asarray(x), np.asarray(y), a[None, :],
-                        n_variants=int(n_variants),
-                        accuracy_floor=float(accuracy_floor),
-                        mesh=mesh, confidence=confidence, ci=ci)
+            # Profiler spans (no-ops unless a profiler is tracing): the call,
+            # and its host phases; `stream` opens the later ones.
+            with jax.profiler.TraceAnnotation("repro.mc.stream") as span:
+                with jax.profiler.TraceAnnotation("repro.mc.prep"):
+                    if accuracy_floor is None:
+                        accuracy_floor = self.score(
+                            x, y, target="circuit") - 0.02
+                    sm = self.stream_machine(
+                        key, method=method or "iid",
+                        mc_chunk=(MC_STREAM_CHUNK if mc_chunk is None
+                                  else mc_chunk),
+                        sigma_scale=sigma_scale, is_scale=is_scale)
+                    a = dse_mod.assignment_from_kernel_map(kmap)
+                b = sm._chunk_size(mesh)
+                span.set_metadata(n_variants=int(n_variants), mc_chunk=b,
+                                  steps=-(-int(n_variants) // b))
+                out = sm.stream(np.asarray(x), np.asarray(y), a[None, :],
+                                n_variants=int(n_variants),
+                                accuracy_floor=float(accuracy_floor),
+                                mesh=mesh, confidence=confidence, ci=ci)
         return StreamingMCResult(
             mean=float(out["mean"][0]), std=float(out["std"][0]),
             worst=float(out["worst"][0]), best=float(out["best"][0]),
