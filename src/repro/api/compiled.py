@@ -1213,19 +1213,22 @@ def _key_data(key: jax.Array) -> np.ndarray:
         return np.asarray(key)
 
 
-#: Bytes one ``(variants, n, P, M)`` f32 temporary of the analog variant
-#: lanes may take.  The measured-curve lookups materialize index and
-#: fraction tensors of that shape (the gather ends XLA's fusion), so B
-#: variants over n rows ask for B*n*P*M*4 bytes each: 26.6 GB at har12
-#: size (256 variants x 512 rows x 66 pairs) on a 16 GB chip.  Past this
-#: bound the variant axis runs in sequential slices of the largest
-#: count that fits.
+#: Bytes the analog variant lanes of one slice may hold in their
+#: ``(variants, n, P, M)``-shaped temporaries.  Per cell the measured-curve
+#: lookups materialize an s32 index (4 B) and, on the TPU, the MXU
+#: lookup's f32 pair ``(f0, f1)`` (8 B; ``core.kernels.curve_pair_mxu``),
+#: so B variants over n rows ask for 12*B*n*P*M bytes: 77 GB at har12
+#: size (256 variants x 512 rows x 66 pairs x M = 745) on a 16 GB chip.
+#: Past this bound the variant axis runs in sequential slices of the
+#: largest count that fits.
 MC_LANE_BYTES = 1 << 28
+_LANE_CELL_BYTES = 4 + 4 * 2
 
 
 def _variant_batch(n_rows: int, n_pairs: int, m_max: int) -> int:
     """Variants per slice of the analog lanes under ``MC_LANE_BYTES``."""
-    return max(1, MC_LANE_BYTES // (4 * n_rows * n_pairs * m_max))
+    return max(1, MC_LANE_BYTES
+               // (_LANE_CELL_BYTES * n_rows * n_pairs * m_max))
 
 
 def _bank_scores_mc(bank: _KernelBank, bv: _BankVariants, xv: jnp.ndarray,

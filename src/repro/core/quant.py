@@ -79,7 +79,8 @@ def best_frac_bits(x: np.ndarray, bits: int) -> int:
     # need qmax * 2^-frac >= amax  =>  frac <= log2(qmax / amax);
     # clamped to the float32-safe exponent range (codes are computed in
     # f32 on device — extreme scales would under/overflow there).
-    frac = int(np.floor(np.log2(qmax / amax) + 1e-9))
+    # A subnormal amax overflows qmax / amax to inf: clip before int().
+    frac = np.floor(np.log2(qmax / amax) + 1e-9)
     return int(np.clip(frac, -(126 - bits), 126))
 
 

@@ -54,6 +54,14 @@ def test_fixed_point_bound(vals, bits):
         assert np.max(np.abs(xq - x)) <= bound
 
 
+def test_fixed_point_bound_subnormal_amax():
+    """A float64 subnormal amax (found by the property test above) clamps
+    to the finest scale instead of overflowing."""
+    assert quant.best_frac_bits(np.array([2.2250738585e-313]), 4) == 126
+    xq, _ = quant.quantize_tensor(np.array([2.2250738585e-313, -1e-320]), 4)
+    np.testing.assert_array_equal(xq, 0.0)
+
+
 def test_csd_and_hardware_class():
     assert quant.weight_hardware_class(0) == "zero"
     for p in (1, 2, 4, 64):

@@ -11,6 +11,7 @@ every test of this kind lives in this one file: only one process at a
 time may load the TPU library, and it keeps it until it exits.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -122,10 +123,24 @@ def test_circuit_fleet_forward_bucket(one_chip):
              _spec(one_chip, (BUCKET,), jnp.int32))
 
 
-def test_mc_analog_lanes_har12_chunk(one_chip):
-    """The streaming engine's analog variant lanes at har12 size: without
-    the variant slicing of ``MC_LANE_BYTES`` the lookups' index tensor
-    alone asks for 26.6 GB on a 16 GB chip."""
+#: The benchmark's balance signoff step: a 256-variant chunk over 128
+#: rows, 3 analog candidates of up to M = 65 support vectors, d = 4.
+BAL_CHUNK, BAL_ROWS, BAL_P, BAL_M, BAL_D = 256, 128, 3, 65, 4
+
+
+def _curve_gathers(hlo: str, n: int) -> list:
+    """The optimized HLO's gathers whose operand is an ``f32[n]`` curve."""
+    shapes = dict(re.findall(r"%([\w.\-]+) = (\w+\[[\d,]*\])", hlo))
+    return [line for line in hlo.splitlines()
+            if (m := re.search(r" gather\(%([\w.\-]+)", line))
+            and shapes.get(m.group(1)) == f"f32[{n}]"]
+
+
+def _compile_mc_lanes(one_chip, B, n, p, m, d):
+    """``_bank_scores_mc`` for B variants over n rows of p analog pairs of
+    M = m support vectors in d inputs, on the default measured curve;
+    checks that every curve lookup went to the MXU, none to a gather."""
+    from repro import obs
     from repro.api import compiled as api
     from repro.core import selection
 
@@ -134,9 +149,9 @@ def test_mc_analog_lanes_har12_chunk(one_chip):
     curve = np.asarray(hw.kernel_curve, np.float32)
 
     def lanes(x, sv, cpos, cneg, vshift, vgain, vcpos, vcneg, voff):
-        z = jnp.zeros((P,), jnp.float32)
+        z = jnp.zeros((p,), jnp.float32)
         bank = api._KernelBank(
-            kind="hw", input_bits=0, pair_idx=np.arange(P), sv=sv,
+            kind="hw", input_bits=0, pair_idx=np.arange(p), sv=sv,
             coef_pos=cpos, coef_neg=cneg, bias_pos=z, bias_neg=z, offset=z,
             gamma=z + 1.0, scale=z + 1.0, shift=z, grid=jnp.asarray(grid),
             curve=jnp.asarray(curve), **api._grid_fast_path(grid))
@@ -145,10 +160,35 @@ def test_mc_analog_lanes_har12_chunk(one_chip):
         return api._bank_scores_mc(bank, bv, x, use_pallas=False,
                                    include_nominal=False)
 
-    B, n, m = MC_CHUNK, MC_ROWS, MC_M
-    assert api._variant_batch(n, P, m) < B
-    _compile(lanes, _spec(one_chip, (n, D)), _spec(one_chip, (P, m, D)),
-             _spec(one_chip, (P, m)), _spec(one_chip, (P, m)),
-             _spec(one_chip, (B, P, m, D)), _spec(one_chip, (B, P, m, D)),
-             _spec(one_chip, (B, P, m)), _spec(one_chip, (B, P, m)),
-             _spec(one_chip, (B, P)))
+    before = obs.snapshot()["lut_mxu_traced"]
+    compiled = _compile(
+        lanes, _spec(one_chip, (n, d)), _spec(one_chip, (p, m, d)),
+        _spec(one_chip, (p, m)), _spec(one_chip, (p, m)),
+        _spec(one_chip, (B, p, m, d)), _spec(one_chip, (B, p, m, d)),
+        _spec(one_chip, (B, p, m)), _spec(one_chip, (B, p, m)),
+        _spec(one_chip, (B, p)))
+    assert obs.snapshot()["lut_mxu_traced"] > before
+    hlo = compiled.as_text()
+    assert not _curve_gathers(hlo, curve.size)
+    return compiled
+
+
+def test_mc_analog_lanes_har12_chunk(one_chip):
+    """The streaming engine's analog variant lanes at har12 size: without
+    the variant slicing of ``MC_LANE_BYTES`` the lookups' temporaries ask
+    for 77 GB on a 16 GB chip."""
+    from repro.api import compiled as api
+
+    assert api._variant_batch(MC_ROWS, P, MC_M) < MC_CHUNK
+    _compile_mc_lanes(one_chip, MC_CHUNK, MC_ROWS, P, MC_M, D)
+
+
+def test_mc_analog_lanes_balance_chunk(one_chip):
+    """The benchmark's signoff lanes: one slice of the whole chunk, every
+    curve lookup a one-hot contraction on the MXU."""
+    from repro.api import compiled as api
+
+    assert api._variant_batch(BAL_ROWS, BAL_P, BAL_M) >= BAL_CHUNK
+    compiled = _compile_mc_lanes(one_chip, BAL_CHUNK, BAL_ROWS, BAL_P,
+                                 BAL_M, BAL_D)
+    assert " convolution(" in compiled.as_text()
