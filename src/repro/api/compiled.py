@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import dse
 from repro.core import kernels as kern
 from repro.core import mcstream, quant
@@ -1214,21 +1215,32 @@ def _key_data(key: jax.Array) -> np.ndarray:
 
 
 #: Bytes the analog variant lanes of one slice may hold in their
-#: ``(variants, n, P, M)``-shaped temporaries.  Per cell the measured-curve
-#: lookups materialize an s32 index (4 B) and, on the TPU, the MXU
-#: lookup's f32 pair ``(f0, f1)`` (8 B; ``core.kernels.curve_pair_mxu``),
-#: so B variants over n rows ask for 12*B*n*P*M bytes: 77 GB at har12
-#: size (256 variants x 512 rows x 66 pairs x M = 745) on a 16 GB chip.
-#: Past this bound the variant axis runs in sequential slices of the
-#: largest count that fits.
-MC_LANE_BYTES = 1 << 28
-_LANE_CELL_BYTES = 4 + 4 * 2
+#: ``(variants, n, P, M)``-shaped temporaries, and the bytes a lane cell
+#: takes of them.  Per cell the lanes keep the s32 segment index, the MXU
+#: lookup's f32 pair ``(f0, f1)`` (``core.kernels.curve_pair_mxu``) and
+#: the f32 offsets, fractions, values and running product of the inputs
+#: live at once: compiled for a TPU v5e at 45 pairs x M = 165 over 128
+#: rows, a slice of 7 to 28 variants held 56-64 B a cell of temporaries
+#: (and each further variant 48-62 B a cell).  So B variants over n rows
+#: ask for 64*B*n*P*M bytes: 412 GB at har12 size (256 variants x 512
+#: rows x 66 pairs x M = 745) on a 16 GB chip.  Past this bound the
+#: variant axis runs in sequential slices of the largest count that fits.
+MC_LANE_BYTES = 1 << 30
+_LANE_CELL_BYTES = 64
 
 
 def _variant_batch(n_rows: int, n_pairs: int, m_max: int) -> int:
     """Variants per slice of the analog lanes under ``MC_LANE_BYTES``."""
     return max(1, MC_LANE_BYTES
                // (_LANE_CELL_BYTES * n_rows * n_pairs * m_max))
+
+
+def _lane_slices(n_lanes: int, n_rows: int, n_pairs: int,
+                 m_max: int) -> int:
+    """Sequential slices ``_bank_scores_mc`` runs ``n_lanes`` variants in
+    (``lax.map`` slices of ``_variant_batch``): 1 where they are one
+    ``vmap``."""
+    return -(-n_lanes // _variant_batch(n_rows, n_pairs, m_max))
 
 
 def _bank_scores_mc(bank: _KernelBank, bv: _BankVariants, xv: jnp.ndarray,
@@ -1559,9 +1571,9 @@ class StreamingMCMachine:
        a pure function of the global index, never of the chunking.
     2. **Score**: the chunk's banks run through the SAME
        ``_all_scores_mc`` lanes as the dense machine (digital lanes
-       broadcast), then the packed-encoder (or, past ``MAX_TABLE_BITS``,
-       the pair-chunked votes) recombination scores every
-       assignment (``_recombine_acc``).
+       broadcast), then the packed-encoder recombination
+       (``_recombine_acc``) or, past ``MAX_TABLE_BITS``, the pair-chunked
+       votes (``dse._votes_accuracy_paired``) scores every assignment.
     3. **Fold**: the ``(B, S)`` chunk accuracies collapse into the
        donated :class:`~repro.core.mcstream.StreamStats` accumulator —
        weighted Welford mean/M2, floor exceedance, extrema, histogram
@@ -1579,11 +1591,15 @@ class StreamingMCMachine:
     with one psum/pmin/pmax merge per chunk.
 
     Measurement: the step's device ops carry the named scopes ``mc.draw``,
-    ``mc.lanes.linear``/``.rbf``/``.hw``, ``mc.recombine`` and ``mc.fold``;
-    :meth:`stream` marks its host phases with profiler spans
-    (``repro.mc.prep``, ``.dispatch``, ``.wait``, ``.finalize``, which
-    record nothing unless a profiler is tracing) and keeps the work
-    totals ``steps_run``, ``variants_run`` and ``lane_cells_run``.
+    ``mc.lanes.linear``/``.rbf``/``.hw``, ``mc.recombine`` (with
+    ``mc.recombine.table`` or ``mc.recombine.votes`` inside it, the path
+    taken, which ``repro.obs.snapshot()`` also counts as
+    ``recombine_table_traced``/``recombine_votes_traced`` when the step is
+    lowered) and ``mc.fold``; :meth:`stream` marks its host phases with
+    profiler spans (``repro.mc.prep``, ``.dispatch``, ``.wait``,
+    ``.finalize``, which record nothing unless a profiler is tracing) and
+    keeps the work totals ``steps_run``, ``variants_run``,
+    ``lane_cells_run`` and ``lane_slices_run``.
     """
 
     def __init__(self, n_classes: int, linear_banks, kernel_banks,
@@ -1634,17 +1650,21 @@ class StreamingMCMachine:
         self._step_jit = jax.jit(self._step, donate_argnums=(0,))
         self._bits_jit = jax.jit(self._bits)
         self._sharded_steps: dict = {}
-        #: Padded ``(Pb, M)`` slots of the variant banks: the analog lane
-        #: cells one variant-row computes.
-        self._variant_slots = sum(
-            int(bank.sv.shape[0] * bank.sv.shape[1])
-            for bank, bc in zip(kernel_banks, stream_consts) if bc is not None)
+        #: Padded ``(Pb, M)`` slot grids of the variant banks, and their
+        #: slots: the analog lane cells one variant-row computes.
+        self._variant_grids = [
+            tuple(int(n) for n in bank.sv.shape[:2])
+            for bank, bc in zip(kernel_banks, stream_consts) if bc is not None]
+        self._variant_slots = sum(p * m for p, m in self._variant_grids)
         #: Work :meth:`stream` dispatched over the machine's life: chunk
-        #: steps, valid variants, and analog lane cells computed (every
-        #: step's chunk x rows x ``_variant_slots``).
+        #: steps, valid variants, analog lane cells computed (every step's
+        #: chunk x rows x ``_variant_slots``), and the sequential slices
+        #: the variant lanes of each step ran in (the most of any bank; 1
+        #: a step where the lanes are one ``vmap``).
         self.steps_run = 0
         self.variants_run = 0
         self.lane_cells_run = 0
+        self.lane_slices_run = 0
 
     # -- in-graph chunk generation ------------------------------------------
 
@@ -1742,11 +1762,15 @@ class StreamingMCMachine:
         bits, w, log_ref = self._bits(x, v_idx, u)
         with jax.named_scope("mc.recombine"):
             if self._table is not None:
-                acc = _recombine_acc(bits, assignments, y, self._table,
-                                     self._weights)
+                with jax.named_scope("mc.recombine.table"):
+                    acc = _recombine_acc(
+                        obs.mark_lowered(bits, "recombine_table_traced"),
+                        assignments, y, self._table, self._weights)
             else:
-                acc = dse._votes_accuracy_paired(
-                    bits, assignments, y, self._vote_a, self._vote_b)
+                with jax.named_scope("mc.recombine.votes"):
+                    acc = dse._votes_accuracy_paired(
+                        obs.mark_lowered(bits, "recombine_votes_traced"),
+                        assignments, y, self._vote_a, self._vote_b)
         return acc, w, log_ref, bits
 
     def _step(self, state, x, v_idx, valid, floor, assignments, y, u):
@@ -1838,6 +1862,14 @@ class StreamingMCMachine:
             b = -(-b // nd) * nd      # round up to a whole per-device slice
         return b
 
+    def _step_slices(self, b: int, n_rows: int, mesh=None) -> int:
+        """Slices of the variant lanes in one step of ``b`` variants over
+        ``n_rows`` rows (each device's share of the chunk on a mesh)."""
+        if mesh is not None:
+            b //= int(np.prod(list(mesh.shape.values())))
+        return max((_lane_slices(b, n_rows, *g) for g in self._variant_grids),
+                   default=0)
+
     def _chunk_inputs(self, start: int, b: int, n_variants: int):
         # Host numpy on purpose: eager jnp ops bake `start` in as a
         # constant and would compile one tiny program per distinct chunk
@@ -1887,6 +1919,7 @@ class StreamingMCMachine:
             state = mcstream.init_stream(
                 int(a.shape[0]), mcstream.hist_bins(int(x.shape[0])))
         cells = b * int(x.shape[0]) * self._variant_slots
+        slices = self._step_slices(b, int(x.shape[0]), mesh)
         with jax.profiler.TraceAnnotation("repro.mc.dispatch"):
             for start in range(0, n_variants, b):
                 v_idx, valid, u = self._chunk_inputs(start, b, n_variants)
@@ -1894,6 +1927,7 @@ class StreamingMCMachine:
                 self.steps_run += 1
                 self.variants_run += min(b, n_variants - start)
                 self.lane_cells_run += cells
+                self.lane_slices_run += slices
         with jax.profiler.TraceAnnotation("repro.mc.wait"):
             state = jax.block_until_ready(state)
         with jax.profiler.TraceAnnotation("repro.mc.finalize"):

@@ -1,5 +1,6 @@
 """Compile counters of the process, from ``jax.monitoring``, and the
-measured-curve lookups counted as programs are lowered.
+measured-curve lookups and Monte-Carlo recombinations counted as programs
+are lowered.
 
 Importing ``repro`` registers the listeners once; :func:`snapshot` returns
 the totals since then, and a caller that wants the compiles of one stretch
@@ -24,7 +25,11 @@ Keys of a snapshot (``_n`` a count of events, ``_s`` their seconds):
   traces both implementations into every jaxpr and keeps one when the
   program is lowered for its platform, so these are counted by
   :func:`mark_lowered`, for the branch kept, as it is lowered (lookups
-  of one shape in one program share a lowering) and on each eager run.
+  of one shape in one program share a lowering) and on each eager run;
+* ``recombine_table_traced``/``recombine_votes_traced``: streaming
+  Monte-Carlo steps lowered with the packed-encoder table recombination
+  or, past ``MAX_TABLE_BITS`` pairs, the pair-chunked votes
+  (``StreamingMCMachine._chunk_acc``), counted the same way.
 
 Nothing here is called while a compiled program runs: the listeners and
 the lowering rule fire only while JAX traces, lowers or compiles.
@@ -49,7 +54,8 @@ _lock = threading.Lock()
 _totals = dict.fromkeys(
     [f"{k}_{u}" for k in _DURATIONS.values() for u in ("n", "s")]
     + ["cache_hits", "cache_retrieval_s", "lut_mxu_traced",
-       "lut_gather_traced"], 0)
+       "lut_gather_traced", "recombine_table_traced",
+       "recombine_votes_traced"], 0)
 
 
 def _bump(key: str) -> None:

@@ -176,7 +176,7 @@ def _compile_mc_lanes(one_chip, B, n, p, m, d):
 def test_mc_analog_lanes_har12_chunk(one_chip):
     """The streaming engine's analog variant lanes at har12 size: without
     the variant slicing of ``MC_LANE_BYTES`` the lookups' temporaries ask
-    for 77 GB on a 16 GB chip."""
+    for 412 GB on a 16 GB chip."""
     from repro.api import compiled as api
 
     assert api._variant_batch(MC_ROWS, P, MC_M) < MC_CHUNK
@@ -192,3 +192,23 @@ def test_mc_analog_lanes_balance_chunk(one_chip):
     compiled = _compile_mc_lanes(one_chip, BAL_CHUNK, BAL_ROWS, BAL_P,
                                  BAL_M, BAL_D)
     assert " convolution(" in compiled.as_text()
+
+
+#: The benchmark's ten-class signoff lanes (``signoff.digits``): a
+#: 256-variant chunk over 128 rows, the 45 analog candidates of the
+#: optdigits machine padded to M = 165, d = 5.
+DIG_CHUNK, DIG_ROWS, DIG_P, DIG_M, DIG_D = 256, 128, 45, 165, 5
+
+
+def test_mc_analog_lanes_digits_slices(one_chip):
+    """The ten-class signoff lanes run in several slices, and one slice's
+    temporaries fit ``MC_LANE_BYTES`` without falling far below it: the
+    per-cell count of ``_variant_batch`` matches what the TPU compile
+    holds, every lookup on the MXU."""
+    from repro.api import compiled as api
+
+    assert api._lane_slices(DIG_CHUNK, DIG_ROWS, DIG_P, DIG_M) > 1
+    compiled = _compile_mc_lanes(one_chip, DIG_CHUNK, DIG_ROWS, DIG_P,
+                                 DIG_M, DIG_D)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert api.MC_LANE_BYTES / 2 <= temp <= api.MC_LANE_BYTES
