@@ -258,7 +258,7 @@ def run_scaling(n_epochs: int = 120, seed: int = 0, mc_seed: int = 0,
 
     # Streamed-vs-dense parity oracle at V = 64: the SAME 64 variants
     # through the dense bit tensor + batched recombination.
-    bits64 = sm.pair_bits_dense(x, np.arange(64))
+    bits64 = sm.pair_bits_dense(x, np.arange(64), a)
     acc64 = dse.assignment_accuracies_mc(bits64, a, y, est.n_classes_)
     warm = sm.stream(x, y, a, n_variants=64, accuracy_floor=floor)
     parity = {
@@ -269,7 +269,7 @@ def run_scaling(n_epochs: int = 120, seed: int = 0, mc_seed: int = 0,
             warm["yield"][0] == (acc64 >= floor).mean()),
     }
 
-    mem = sm.step_memory_analysis(n_x, 1)
+    mem = sm.step_memory_analysis(n_x, assignments=a)
     mem_rec = None if mem is None else {
         "temp_bytes": int(mem.temp_size_in_bytes),
         "argument_bytes": int(mem.argument_size_in_bytes),

@@ -45,8 +45,11 @@ every candidate machine's output without re-evaluating any classifier.
 """
 from __future__ import annotations
 
+import copy
 import dataclasses
+import functools
 import json
+import operator
 from typing import Optional, Sequence
 
 import jax
@@ -56,7 +59,7 @@ import numpy as np
 from repro import obs
 from repro.core import dse
 from repro.core import kernels as kern
-from repro.core import mcstream, quant
+from repro.core import mcstream, quant, trainer
 from repro.core.analog import (
     N_ALPHA_OFFSETS,
     N_GAUSS_OFFSETS,
@@ -448,16 +451,24 @@ def _build_banks(specs: list) -> tuple[list[_LinearBank], list[_KernelBank]]:
             [_KernelBank.build(g) for g in kernel_groups])
 
 
-def _inverse_perm(linear_banks, kernel_banks, n_total: int) -> jnp.ndarray:
-    """Column order after bank concatenation -> lowering order inversion."""
+def _inverse_perm(linear_banks, kernel_banks, n_total: int,
+                  stand_in: Optional[dict] = None) -> jnp.ndarray:
+    """Column order after bank concatenation -> lowering order inversion.
+
+    ``stand_in`` maps a lowering column that no bank holds to the lowering
+    column it reads instead."""
+    stand_in = stand_in or {}
     order = np.concatenate(
         [b.pair_idx for b in linear_banks]
         + [b.pair_idx for b in kernel_banks]).astype(np.int64)
-    if order.shape[0] != n_total:
+    if order.shape[0] + len(stand_in) != n_total:
         raise ValueError(
-            f"{order.shape[0]} lowered columns != {n_total} expected")
-    inv = np.empty_like(order)
-    inv[order] = np.arange(n_total)
+            f"{order.shape[0]} lowered columns + {len(stand_in)} stand-ins "
+            f"!= {n_total} expected")
+    inv = np.empty(n_total, np.int64)
+    inv[order] = np.arange(order.shape[0])
+    for col, src in stand_in.items():
+        inv[col] = inv[src]
     return jnp.asarray(inv)
 
 
@@ -1524,6 +1535,50 @@ jax.tree_util.register_dataclass(
     meta_fields=["params", "u_offset", "u_width", "true_dim"])
 
 
+#: A restricted step's analog pairs run in at most this many size groups,
+#: each a bank padded to its own largest M.  A group also costs, besides
+#: its cells, about what ``_LANE_GROUP_CELLS`` lane cells of a variant-row
+#: cost (its own draw, lookup and rail ops), so a group whose padding
+#: saving is smaller is not split off.
+_MAX_LANE_GROUPS = 4
+_LANE_GROUP_CELLS = 2
+
+
+@dataclasses.dataclass(eq=False)
+class _StreamLanes:
+    """The kernel banks one streaming step lowers (DESIGN.md §10.6).
+
+    The candidate lanes (``needed`` None) hold every pair's candidates in
+    the banks of :func:`compile_mc_stream`.  Restricted lanes keep the
+    variant-free banks and lower only the analog pairs ``needed`` names,
+    in size groups.  ``inv_perm`` maps each lowering column to its bank
+    column; the analog column of a pair outside ``needed`` reads that
+    pair's linear column, so the recombination sees both bits equal.
+    """
+
+    kernel_banks: list
+    stream_consts: list        # aligned with kernel_banks; None: variant-free
+    inv_perm: jnp.ndarray
+    needed: Optional[frozenset] = None
+
+    @property
+    def grids(self) -> list:
+        """Padded ``(Pb, M)`` slot grids of the variant banks."""
+        return [tuple(int(n) for n in bank.sv.shape[:2])
+                for bank, bc in zip(self.kernel_banks, self.stream_consts)
+                if bc is not None]
+
+    @property
+    def slots(self) -> int:
+        """Analog lane cells one variant-row computes."""
+        return sum(p * m for p, m in self.grids)
+
+
+jax.tree_util.register_dataclass(
+    _StreamLanes, data_fields=["kernel_banks", "stream_consts", "inv_perm"],
+    meta_fields=["needed"])
+
+
 def _recombine_acc(bits4, assignments, y, table, weights,
                    s_chunk: int = _RECOMBINE_CHUNK):
     """Streamed bit-recombination: ``bits4 (B, n, P, 2) -> acc (B, S)`` f32.
@@ -1599,15 +1654,23 @@ class StreamingMCMachine:
     profiler spans (``repro.mc.prep``, ``.dispatch``, ``.wait``,
     ``.finalize``, which record nothing unless a profiler is tracing) and
     keeps the work totals ``steps_run``, ``variants_run``,
-    ``lane_cells_run`` and ``lane_slices_run``.
+    ``lane_cells_run``, ``lane_slices_run`` and ``restricted_steps_run``.
+
+    Restricted step (DESIGN.md §10.6): where ``method == 'iid'`` and the
+    assignments select a strict subset of the analog candidates,
+    :meth:`stream` runs a step that lowers only those analog pairs, in
+    size groups (``restrict``, built by :func:`compile_mc_stream`), and
+    ``repro.obs.snapshot()`` counts ``mc_lanes_restricted_traced`` as such
+    a step is lowered.  Every other stream runs the candidate step.
     """
 
-    def __init__(self, n_classes: int, linear_banks, kernel_banks,
-                 stream_consts, method: str, mc_chunk: int,
+    def __init__(self, n_classes: int, linear_banks, lanes: _StreamLanes,
+                 method: str, mc_chunk: int,
                  sigma_scale: float, is_scale: float,
                  key_data: Optional[np.ndarray] = None,
                  use_pallas: Optional[bool] = None,
-                 interpret: Optional[bool] = None):
+                 interpret: Optional[bool] = None,
+                 restrict=None):
         self.n_classes = int(n_classes)
         self.n_pairs = len(class_pairs(self.n_classes))
         if method not in STREAM_METHODS:
@@ -1622,15 +1685,15 @@ class StreamingMCMachine:
         self.is_scale = float(is_scale)
         self.key_data = None if key_data is None else np.asarray(key_data)
         self._linear_banks = linear_banks
-        self._kernel_banks = kernel_banks
-        self._stream_consts = stream_consts   # aligned with kernel_banks
-        self.n_features = _bank_feature_dim(linear_banks, kernel_banks)
+        self._lanes = lanes
+        #: ``needed -> _StreamLanes`` of a restricted step; None: no
+        #: restricted step.
+        self._restrict = restrict
+        self.n_features = _bank_feature_dim(linear_banks, lanes.kernel_banks)
         if use_pallas is None:
             use_pallas = jax.default_backend() == "tpu"
         self.use_pallas = bool(use_pallas)
         self.interpret = interpret
-        self._inv_perm = _inverse_perm(linear_banks, kernel_banks,
-                                       2 * self.n_pairs)
         # Recombination constants: packed encoder table in the FE regime,
         # the pair-chunked votes matmul (dse._votes_accuracy_paired)
         # beyond it — same flat-memory contract either way.
@@ -1640,9 +1703,9 @@ class StreamingMCMachine:
         #: Flat mismatch dims over the padded slot grids (the QMC block
         #: width) and over the true circuits (the IS log-weight D).
         self.mismatch_dim = sum(
-            c.u_width for c in stream_consts if c is not None)
+            c.u_width for c in lanes.stream_consts if c is not None)
         self.true_dim = sum(
-            c.true_dim for c in stream_consts if c is not None)
+            c.true_dim for c in lanes.stream_consts if c is not None)
         self._sampler = None
         if method in ("sobol", "stratified"):
             self._sampler = mcstream.QMCSampler(
@@ -1650,21 +1713,25 @@ class StreamingMCMachine:
         self._step_jit = jax.jit(self._step, donate_argnums=(0,))
         self._bits_jit = jax.jit(self._bits)
         self._sharded_steps: dict = {}
-        #: Padded ``(Pb, M)`` slot grids of the variant banks, and their
-        #: slots: the analog lane cells one variant-row computes.
-        self._variant_grids = [
-            tuple(int(n) for n in bank.sv.shape[:2])
-            for bank, bc in zip(kernel_banks, stream_consts) if bc is not None]
-        self._variant_slots = sum(p * m for p, m in self._variant_grids)
+        #: Restricted views of this machine, by the analog pairs they
+        #: lower (:meth:`_view`).
+        self._restricted: dict = {}
+        #: Pairs whose analog candidate runs in the variant lanes.
+        self._analog_pairs = frozenset(
+            int(q) - self.n_pairs
+            for bank, bc in zip(lanes.kernel_banks, lanes.stream_consts)
+            if bc is not None for q in bank.pair_idx)
         #: Work :meth:`stream` dispatched over the machine's life: chunk
         #: steps, valid variants, analog lane cells computed (every step's
-        #: chunk x rows x ``_variant_slots``), and the sequential slices
-        #: the variant lanes of each step ran in (the most of any bank; 1
-        #: a step where the lanes are one ``vmap``).
+        #: chunk x rows x the slots of the lanes it ran), the sequential
+        #: slices the variant lanes of each step ran in (the most of any
+        #: bank; 1 a step where the lanes are one ``vmap``), and the steps
+        #: that ran restricted lanes.
         self.steps_run = 0
         self.variants_run = 0
         self.lane_cells_run = 0
         self.lane_slices_run = 0
+        self.restricted_steps_run = 0
 
     # -- in-graph chunk generation ------------------------------------------
 
@@ -1727,7 +1794,8 @@ class StreamingMCMachine:
     def _chunk_banks(self, v_idx: jnp.ndarray, u: jnp.ndarray):
         """All banks' variant chunks + the chunk's sampling weights (B,)."""
         bank_variants, sumsq = [], jnp.zeros(v_idx.shape[0], jnp.float32)
-        for bank, bc in zip(self._kernel_banks, self._stream_consts):
+        for bank, bc in zip(self._lanes.kernel_banks,
+                            self._lanes.stream_consts):
             if bc is None:
                 bank_variants.append(None)
                 continue
@@ -1782,20 +1850,28 @@ class StreamingMCMachine:
             return mcstream.update_stream(state, acc, w, valid, floor,
                                           log_ref=log_ref)
 
-    def _bits(self, x, v_idx, u):
-        """Chunk bits ``(B, n, P, 2)`` + weights relative to the chunk's
-        own log-scale (also returned): the step's draws and lanes, and
-        un-donated under ``_bits_jit``, the dense oracle."""
+    def _scores(self, x, v_idx, u):
+        """Chunk scores ``(B, n, P, 2)`` + weights relative to the chunk's
+        own log-scale (also returned): the step's draws and lanes."""
         with jax.named_scope("mc.draw"):
             bank_variants, w, log_ref = self._chunk_banks(v_idx, u)
         flat = _all_scores_mc(
-            x, self._linear_banks, self._kernel_banks, bank_variants,
-            self._inv_perm, int(v_idx.shape[0]), False, self.use_pallas,
-            interpret=self.interpret)                       # (B, n, 2P)
+            x, self._linear_banks, self._lanes.kernel_banks, bank_variants,
+            self._lanes.inv_perm, int(v_idx.shape[0]), False,
+            self.use_pallas, interpret=self.interpret)      # (B, n, 2P)
         with jax.named_scope("mc.lanes.hw"):
+            if self._lanes.needed is not None:
+                flat = obs.mark_lowered(flat, "mc_lanes_restricted_traced")
             scores = jnp.stack(
                 [flat[..., : self.n_pairs], flat[..., self.n_pairs:]],
                 axis=-1)
+        return scores, w, log_ref
+
+    def _bits(self, x, v_idx, u):
+        """Chunk bits ``(B, n, P, 2)`` + the weights of :meth:`_scores`;
+        un-donated under ``_bits_jit``, the dense oracle."""
+        scores, w, log_ref = self._scores(x, v_idx, u)
+        with jax.named_scope("mc.lanes.hw"):
             return (scores >= 0.0).astype(jnp.int32), w, log_ref
 
     # -- sharded step (variant axis over a mesh) -----------------------------
@@ -1853,6 +1929,43 @@ class StreamingMCMachine:
             self._sharded_steps[mesh] = self._make_sharded_step(mesh)
         return self._sharded_steps[mesh]
 
+    # -- restricted step ------------------------------------------------------
+
+    def _view(self, a: np.ndarray) -> "StreamingMCMachine":
+        """The machine whose step runs the ``(S, P)`` assignments ``a``.
+
+        Where ``method == 'iid'`` and ``a`` selects (column = 1) a strict
+        subset of the analog candidates, a view of this machine whose
+        lanes lower only those pairs: the same code, its own jitted step,
+        dense oracle and sharded steps, built once per set of pairs.
+        Otherwise the machine itself: every other method lays its draws
+        (the ``is`` log-weight, the QMC block) over every candidate slot,
+        and a sweep selects every pair.
+        """
+        if self._restrict is None or self.method != "iid":
+            return self
+        selected = np.flatnonzero(np.asarray(a).any(axis=0))
+        needed = self._analog_pairs.intersection(int(i) for i in selected)
+        if needed == self._analog_pairs:
+            return self
+        if needed not in self._restricted:
+            view = copy.copy(self)
+            view._lanes = self._restrict(needed)
+            view._restrict = None
+            view._restricted = {}
+            view._sharded_steps = {}
+            view._step_jit = jax.jit(view._step, donate_argnums=(0,))
+            view._bits_jit = jax.jit(view._bits)
+            self._restricted[needed] = view
+        return self._restricted[needed]
+
+    def lane_grids(self, assignments=None) -> list:
+        """Padded ``(Pb, M)`` slot grids of the variant banks in the step
+        that runs ``assignments`` (None: the candidate step)."""
+        run = self if assignments is None \
+            else self._view(self._assignment_matrix(assignments))
+        return run._lanes.grids
+
     # -- host driver ---------------------------------------------------------
 
     def _chunk_size(self, mesh=None) -> int:
@@ -1867,7 +1980,7 @@ class StreamingMCMachine:
         ``n_rows`` rows (each device's share of the chunk on a mesh)."""
         if mesh is not None:
             b //= int(np.prod(list(mesh.shape.values())))
-        return max((_lane_slices(b, n_rows, *g) for g in self._variant_grids),
+        return max((_lane_slices(b, n_rows, *g) for g in self._lanes.grids),
                    default=0)
 
     def _chunk_inputs(self, start: int, b: int, n_variants: int):
@@ -1882,18 +1995,21 @@ class StreamingMCMachine:
             u = np.zeros((b, 0), np.float32)
         return v_idx, valid, u
 
+    def _assignment_matrix(self, assignments) -> np.ndarray:
+        a = np.atleast_2d(np.asarray(assignments)).astype(np.int32)
+        if a.shape[1] != self.n_pairs:
+            raise ValueError(
+                f"assignments have {a.shape[1]} pairs, machine has "
+                f"{self.n_pairs}")
+        return a
+
     def _prep(self, x, y, assignments):
         x = jnp.asarray(np.asarray(x), jnp.float32)
         if x.ndim != 2 or (self.n_features and x.shape[1] != self.n_features):
             raise ValueError(
                 f"expected (n, {self.n_features}) inputs, got shape {x.shape}")
         y = jnp.asarray(np.asarray(y), jnp.int32)
-        a = np.atleast_2d(np.asarray(assignments)).astype(np.int32)
-        if a.shape[1] != self.n_pairs:
-            raise ValueError(
-                f"assignments have {a.shape[1]} pairs, machine has "
-                f"{self.n_pairs}")
-        return x, y, jnp.asarray(a)
+        return x, y, self._assignment_matrix(assignments)
 
     def stream(self, x, y, assignments, n_variants: int,
                accuracy_floor: float, mesh=None,
@@ -1907,19 +2023,25 @@ class StreamingMCMachine:
         ``mesh``: a ``make_variant_mesh`` shards each chunk's variant
         axis across devices (chunk size rounds up to a whole per-device
         slice; the validity mask keeps the padded tail inert).
+
+        The step is the restricted one where :meth:`_view` says so, from
+        ``assignments`` and the method, before the first dispatch.
         """
         if n_variants < 1:
             raise ValueError(f"n_variants must be >= 1, got {n_variants}")
         with jax.profiler.TraceAnnotation("repro.mc.prep"):
             x, y, a = self._prep(x, y, assignments)
+            run = self._view(a)
+            a = jnp.asarray(a)
             b = self._chunk_size(mesh)
-            step = (self._step_jit if mesh is None
-                    else self._sharded_step(mesh))
+            step = (run._step_jit if mesh is None
+                    else run._sharded_step(mesh))
             floor = jnp.float32(accuracy_floor)
             state = mcstream.init_stream(
                 int(a.shape[0]), mcstream.hist_bins(int(x.shape[0])))
-        cells = b * int(x.shape[0]) * self._variant_slots
-        slices = self._step_slices(b, int(x.shape[0]), mesh)
+        cells = b * int(x.shape[0]) * run._lanes.slots
+        slices = run._step_slices(b, int(x.shape[0]), mesh)
+        restricted = int(run is not self)
         with jax.profiler.TraceAnnotation("repro.mc.dispatch"):
             for start in range(0, n_variants, b):
                 v_idx, valid, u = self._chunk_inputs(start, b, n_variants)
@@ -1928,6 +2050,7 @@ class StreamingMCMachine:
                 self.variants_run += min(b, n_variants - start)
                 self.lane_cells_run += cells
                 self.lane_slices_run += slices
+                self.restricted_steps_run += restricted
         with jax.profiler.TraceAnnotation("repro.mc.wait"):
             state = jax.block_until_ready(state)
         with jax.profiler.TraceAnnotation("repro.mc.finalize"):
@@ -1938,12 +2061,17 @@ class StreamingMCMachine:
         out["accuracy_floor"] = float(accuracy_floor)
         return out
 
-    def pair_bits_dense(self, x, v_idx) -> np.ndarray:
+    def pair_bits_dense(self, x, v_idx, assignments=None) -> np.ndarray:
         """Dense oracle: the bit tensor ``(B, n, P, 2)`` of the GLOBAL
         variant indices ``v_idx`` — the exact bits the streamed chunks
         fold away.  Small-V parity tests recombine these through the
         dense ``dse.assignment_accuracies_mc`` path and compare against
-        the streamed accumulators."""
+        the streamed accumulators.  ``assignments``: the bits of the step
+        that streams them (None: the candidate step)."""
+        if assignments is not None:
+            run = self._view(self._assignment_matrix(assignments))
+            if run is not self:
+                return run.pair_bits_dense(x, v_idx)
         x = jnp.asarray(np.asarray(x), jnp.float32)
         v_idx = np.asarray(v_idx, np.int32)
         if self._sampler is not None:
@@ -1976,27 +2104,36 @@ class StreamingMCMachine:
         return np.asarray(w, np.float64) * np.exp(float(log_ref))
 
     def step_memory_analysis(self, n_val: int, n_assignments: int = 1,
-                             mesh=None):
+                             mesh=None, assignments=None):
         """XLA ``memory_analysis`` of the compiled chunk step at the given
         validation/assignment shapes — the object the flat-memory CI gate
         inspects.  Returns None when the backend does not report one."""
-        return self.compiled_step(n_val, n_assignments,
-                                  mesh).memory_analysis()
+        return self.compiled_step(n_val, n_assignments, mesh,
+                                  assignments).memory_analysis()
 
-    def compiled_step(self, n_val: int, n_assignments: int = 1, mesh=None):
+    def compiled_step(self, n_val: int, n_assignments: int = 1, mesh=None,
+                      assignments=None):
         """The chunk step compiled at the given validation/assignment
         shapes.  Its ``as_text()`` names each device op and carries the
         op's scope (``mc.draw``, ``mc.lanes.*``, ...) in the HLO metadata
         ``op_name``, which a profiler trace's op events on the TPU do
-        not."""
+        not.  ``assignments``: the step :meth:`stream` runs them with
+        (their count replaces ``n_assignments``); None: the candidate
+        step."""
+        if assignments is None:
+            run = self
+            a = jnp.zeros((int(n_assignments), self.n_pairs), jnp.int32)
+        else:
+            a = self._assignment_matrix(assignments)
+            run = self._view(a)
+            a = jnp.asarray(a)
         b = self._chunk_size(mesh)
         x = jnp.zeros((int(n_val), self.n_features), jnp.float32)
         y = jnp.zeros((int(n_val),), jnp.int32)
-        a = jnp.zeros((int(n_assignments), self.n_pairs), jnp.int32)
         state = mcstream.init_stream(
-            int(n_assignments), mcstream.hist_bins(int(n_val)))
+            int(a.shape[0]), mcstream.hist_bins(int(n_val)))
         v_idx, valid, u = self._chunk_inputs(0, b, b)
-        step = self._step_jit if mesh is None else self._sharded_step(mesh)
+        step = run._step_jit if mesh is None else run._sharded_step(mesh)
         return step.lower(state, x, v_idx, valid, jnp.float32(0.5), a, y,
                           u).compile()
 
@@ -2022,6 +2159,7 @@ def compile_mc_stream(
     :func:`compile_variants`'s one-shot ``(V, ...)`` sampling — the
     streamed engine's dense oracle is its own
     :meth:`StreamingMCMachine.pair_bits_dense`, not the old machine.)
+    A restricted step (:func:`_restricted_lanes`) keeps each pair's key.
 
     ``method``: ``'iid'`` Gaussian draws, ``'sobol'`` scrambled Sobol' /
     ``'stratified'`` Latin-hypercube over the reduced mismatch space
@@ -2071,11 +2209,66 @@ def compile_mc_stream(
             int(bank.sv.shape[2]), u_offset)
         u_offset += bc.u_width
         stream_consts.append(bc)
+    lanes = _StreamLanes(kernel_banks, stream_consts,
+                         _inverse_perm(linear_banks, kernel_banks, 2 * p))
+    analog_specs = {s.pair: s for s in specs if s.pair in analog_clfs}
+    restrict = functools.partial(
+        _restricted_lanes, linear_banks,
+        [b for b, bc in zip(kernel_banks, stream_consts) if bc is None],
+        analog_specs, analog_clfs, analog_keys, p)
     return StreamingMCMachine(
-        n_classes, linear_banks, kernel_banks, stream_consts,
+        n_classes, linear_banks, lanes,
         method=method, mc_chunk=mc_chunk, sigma_scale=sigma_scale,
         is_scale=is_scale, key_data=_key_data(key),
-        use_pallas=use_pallas, interpret=interpret)
+        use_pallas=use_pallas, interpret=interpret, restrict=restrict)
+
+
+def _size_groups(n_support) -> list:
+    """Size groups of analog pairs of true M ``n_support``: index arrays
+    into it, at most ``_MAX_LANE_GROUPS``, minimising the padded cells
+    plus ``_LANE_GROUP_CELLS`` a group."""
+    return trainer.size_sorted_groups(
+        n_support, _MAX_LANE_GROUPS,
+        lambda n, m: n * m + _LANE_GROUP_CELLS, operator.add)
+
+
+def _restricted_lanes(linear_banks, fixed_banks, analog_specs, analog_clfs,
+                      analog_keys, n_pairs: int,
+                      needed: frozenset) -> _StreamLanes:
+    """Lanes of a step that lowers only the analog pairs ``needed``.
+
+    The variant-free banks ``fixed_banks`` stay as they are.  The needed
+    analog specs (``analog_specs`` by lowering column ``n_pairs + i``) are
+    sorted by true M and split into at most ``_MAX_LANE_GROUPS`` contiguous
+    groups that minimise the padded cells, ``count x group max``, plus
+    ``_LANE_GROUP_CELLS`` a group (:func:`_size_groups`); each group is a
+    bank padded to its own max.  Each pair keeps its key, and
+    its draw at the group's M is the first rows of its draw at the
+    candidate M (``jax_threefry_partitionable``), so every true cell gets
+    the mismatch the candidate step gives it.  The analog column of an
+    analog pair outside ``needed`` reads the pair's linear column.
+    """
+    kernel_banks = list(fixed_banks)
+    stream_consts = [None] * len(kernel_banks)
+    _, groups = _group_specs([analog_specs[n_pairs + i]
+                              for i in sorted(needed)])
+    u_offset = 0
+    for g in groups:
+        for idx in _size_groups([s.sv.shape[0] for s in g]):
+            sub = [g[k] for k in idx]
+            bank = _KernelBank.build(sub)
+            bc = _stream_bank_const(
+                sub, analog_clfs, analog_keys, int(bank.sv.shape[1]),
+                int(bank.sv.shape[2]), u_offset)
+            u_offset += bc.u_width
+            kernel_banks.append(bank)
+            stream_consts.append(bc)
+    stand_in = {col: col - n_pairs for col in analog_specs
+                if col - n_pairs not in needed}
+    inv_perm = _inverse_perm(linear_banks, kernel_banks, 2 * n_pairs,
+                             stand_in)
+    return _StreamLanes(kernel_banks, stream_consts, inv_perm,
+                        frozenset(needed))
 
 
 def _stream_bank_const(group, analog_clfs, analog_keys, m_max: int, d: int,

@@ -46,7 +46,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Optional, Sequence
+from typing import Callable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -663,6 +663,53 @@ def _cv_grid_sharded(padded, kind, gammas, cs, n_epochs, mesh,
 # ---------------------------------------------------------------------------
 
 
+def size_sorted_groups(n_true: Sequence[int], n_groups: int,
+                       cost: Callable[[int, int], float],
+                       combine: Callable[[float, float], float] = max
+                       ) -> list[np.ndarray]:
+    """Partition items into ``<= n_groups`` contiguous size-sorted groups.
+
+    Items are sorted by true size, and the group boundaries are chosen by
+    dynamic programming to minimise ``combine`` over the groups of
+    ``cost(count, group_max)``: ``max`` minimises the makespan of
+    per-device programs, ``operator.add`` the total work of programs that
+    run one after the other.  Of the group counts that reach the same
+    minimum the fewest wins.
+
+    Returns a list of index arrays into the ORIGINAL item order; their
+    concatenation is a permutation of ``range(len(n_true))``.
+    O(n_groups * P^2).
+    """
+    p = len(n_true)
+    if p == 0:
+        return []
+    n_groups = max(1, min(int(n_groups), p))
+    order = np.argsort(np.asarray(n_true), kind="stable")
+    sizes = np.asarray(n_true)[order].astype(np.int64)
+
+    inf = float("inf")
+    best = [[inf] * (n_groups + 1) for _ in range(p + 1)]
+    cut = [[0] * (n_groups + 1) for _ in range(p + 1)]
+    best[0][0] = 0.0
+    for j in range(1, p + 1):
+        for s in range(1, min(n_groups, j) + 1):
+            for i in range(s - 1, j):
+                if best[i][s - 1] == inf:
+                    continue
+                # group = sorted items [i, j)
+                c = combine(best[i][s - 1], cost(j - i, int(sizes[j - 1])))
+                if c < best[j][s]:
+                    best[j][s], cut[j][s] = c, i
+    s_best = min(range(1, n_groups + 1), key=lambda s: best[p][s])
+    bounds, j = [], p
+    for s in range(s_best, 0, -1):
+        i = cut[j][s]
+        bounds.append((i, j))
+        j = i
+    bounds.reverse()
+    return [order[i:j] for i, j in bounds]
+
+
 def shard_lane_layout(n_true: Sequence[int], n_shards: int
                       ) -> list[np.ndarray]:
     """Partition pairs into ``<= n_shards`` contiguous size-sorted shards.
@@ -671,48 +718,19 @@ def shard_lane_layout(n_true: Sequence[int], n_shards: int
     lane pay ``n_max^2`` work; with the long-tailed subset sizes of a
     K>=10 OvO grid (har12: 198..1582) most of that is padding.  This
     layout sorts pairs by true subset size and chooses shard boundaries
-    by dynamic programming to minimize the MAKESPAN of padded work,
-    modeling a shard's cost as ``count * shard_max^2`` (the blocked
-    solver's dominant term).  Each shard is then trimmed to its own max
-    (``PaddedPairs.trim``) and dispatched as its own program, so the
-    padding waste is bounded by the within-shard size spread rather than
-    the global one.
+    by dynamic programming (`size_sorted_groups`) to minimize the
+    MAKESPAN of padded work, modeling a shard's cost as
+    ``count * shard_max^2`` (the blocked solver's dominant term).  Each
+    shard is then trimmed to its own max (``PaddedPairs.trim``) and
+    dispatched as its own program, so the padding waste is bounded by the
+    within-shard size spread rather than the global one.
 
     Returns a list of index arrays into the ORIGINAL pair order; their
     concatenation is a permutation of ``range(len(n_true))``.  At
     ``n_shards=1`` this degenerates to the seed layout (one shard, global
     max).  O(n_shards * P^2) — trivial at P=66.
     """
-    p = len(n_true)
-    if p == 0:
-        return []
-    n_shards = max(1, min(int(n_shards), p))
-    order = np.argsort(np.asarray(n_true), kind="stable")
-    sizes = np.asarray(n_true)[order].astype(np.int64)
-
-    def cost(i, j):  # shard = sorted pairs [i, j)
-        return int(j - i) * int(sizes[j - 1]) ** 2
-
-    inf = float("inf")
-    best = [[inf] * (n_shards + 1) for _ in range(p + 1)]
-    cut = [[0] * (n_shards + 1) for _ in range(p + 1)]
-    best[0][0] = 0.0
-    for j in range(1, p + 1):
-        for s in range(1, min(n_shards, j) + 1):
-            for i in range(s - 1, j):
-                if best[i][s - 1] == inf:
-                    continue
-                c = max(best[i][s - 1], cost(i, j))
-                if c < best[j][s]:
-                    best[j][s], cut[j][s] = c, i
-    s_best = min(range(1, n_shards + 1), key=lambda s: best[p][s])
-    bounds, j = [], p
-    for s in range(s_best, 0, -1):
-        i = cut[j][s]
-        bounds.append((i, j))
-        j = i
-    bounds.reverse()
-    return [order[i:j] for i, j in bounds]
+    return size_sorted_groups(n_true, n_shards, lambda n, m: n * m ** 2)
 
 
 def family_cv_grid_size_sharded(

@@ -29,7 +29,10 @@ Keys of a snapshot (``_n`` a count of events, ``_s`` their seconds):
 * ``recombine_table_traced``/``recombine_votes_traced``: streaming
   Monte-Carlo steps lowered with the packed-encoder table recombination
   or, past ``MAX_TABLE_BITS`` pairs, the pair-chunked votes
-  (``StreamingMCMachine._chunk_acc``), counted the same way.
+  (``StreamingMCMachine._chunk_acc``), counted the same way;
+* ``mc_lanes_restricted_traced``: streaming Monte-Carlo steps lowered
+  with restricted lanes, only the analog pairs the assignments select
+  (``StreamingMCMachine._scores``), counted the same way.
 
 Nothing here is called while a compiled program runs: the listeners and
 the lowering rule fire only while JAX traces, lowers or compiles.
@@ -55,7 +58,7 @@ _totals = dict.fromkeys(
     [f"{k}_{u}" for k in _DURATIONS.values() for u in ("n", "s")]
     + ["cache_hits", "cache_retrieval_s", "lut_mxu_traced",
        "lut_gather_traced", "recombine_table_traced",
-       "recombine_votes_traced"], 0)
+       "recombine_votes_traced", "mc_lanes_restricted_traced"], 0)
 
 
 def _bump(key: str) -> None:

@@ -21,6 +21,7 @@ import pytest
 from repro import obs
 from repro.api import MixedKernelSVM
 from repro.api import compiled as api
+from repro.core import dse
 from repro.core.ovo import MAX_TABLE_BITS
 from repro.data import datasets
 
@@ -137,15 +138,17 @@ def test_ten_class_signoff_agrees_with_reference(ten_class):
 
 
 def test_sliced_lanes_match_one_slice(ten_class, monkeypatch):
-    """With ``MC_LANE_BYTES`` cut so a chunk's variant lanes run in 4
-    slices (3 of 5 variants and 1 of 1), every variant's correct-row count
-    and the bits are those of one slice, and ``lane_slices_run`` counts
-    the slices of every step."""
+    """With ``MC_LANE_BYTES`` cut so the largest bank of the signoff's
+    (restricted) step runs a chunk's variant lanes in 4 slices (3 of 5
+    variants and 1 of 1), every variant's correct-row count and the bits
+    are those of one slice, and ``lane_slices_run`` counts the slices of
+    every step."""
     path, x, y = ten_class
+    a = dse.assignment_from_kernel_map(_kernel_map(45))[None, :]
     got, sm = _signoff(path, x, y)
-    assert sm.lane_slices_run == sm.steps_run
-    bits = sm.pair_bits_dense(x, np.arange(CHUNK))
-    (grid,) = sm._variant_grids
+    assert sm.lane_slices_run == sm.steps_run == sm.restricted_steps_run
+    bits = sm.pair_bits_dense(x, np.arange(CHUNK), a)
+    grid = max(sm.lane_grids(a), key=lambda g: g[0] * g[1])
     cells = ROWS * grid[0] * grid[1]
     monkeypatch.setattr(api, "MC_LANE_BYTES", 5 * api._LANE_CELL_BYTES * cells)
     assert api._variant_batch(ROWS, *grid) == 5
@@ -153,7 +156,7 @@ def test_sliced_lanes_match_one_slice(ten_class, monkeypatch):
     assert sm_s.lane_slices_run == 4 * sm_s.steps_run
     np.testing.assert_array_equal(got_s["rows"], got["rows"])
     np.testing.assert_array_equal(
-        sm_s.pair_bits_dense(x, np.arange(CHUNK)), bits)
+        sm_s.pair_bits_dense(x, np.arange(CHUNK), a), bits)
 
 
 def test_lane_cell_bytes_cover_the_step(ten_class, monkeypatch):
@@ -167,7 +170,7 @@ def test_lane_cell_bytes_cover_the_step(ten_class, monkeypatch):
     for batch in (CHUNK, 4):
         sm = MixedKernelSVM.load(path).stream_machine(
             jax.random.PRNGKey(KEY), method="iid", mc_chunk=CHUNK)
-        (grid,) = sm._variant_grids
+        (grid,) = sm.lane_grids()
         cells = ROWS * grid[0] * grid[1]
         monkeypatch.setattr(api, "MC_LANE_BYTES",
                             batch * api._LANE_CELL_BYTES * cells)

@@ -326,9 +326,13 @@ def test_hist_quantiles_exact_on_grid():
 # -- the sharded leg ----------------------------------------------------------
 
 
-def test_sharded_stream_matches_local():
+@pytest.mark.parametrize("assignment,restricted", [
+    ([1, 1, 1], 0), ([1, 1, 0], 1)], ids=["candidate", "restricted"])
+def test_sharded_stream_matches_local(assignment, restricted):
     """shard_map over the variants axis reproduces the single-device
-    stream (8 fake devices, subprocess so XLA_FLAGS doesn't leak)."""
+    stream (8 fake devices, subprocess so XLA_FLAGS doesn't leak): with
+    every pair selected (the candidate step) and with one of the two
+    analog pairs (the restricted step, DESIGN.md §10.6)."""
     body = textwrap.dedent("""
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -342,7 +346,7 @@ def test_sharded_stream_matches_local():
         rng = np.random.default_rng(1)
         x = rng.normal(size=(40, 3)).astype(np.float32)
         y = rng.integers(0, 3, size=40).astype(np.int32)
-        a = np.ones((1, 3), bool)
+        a = np.array([%r], bool)
         cands = _tiny_candidates()
         sm = api.compile_mc_stream(cands, n_classes=3,
                                    key=jax.random.PRNGKey(0), mc_chunk=12)
@@ -351,13 +355,14 @@ def test_sharded_stream_matches_local():
         lo = sm.stream(x, y, a, n_variants=37, accuracy_floor=0.6)
         sh = sm.stream(x, y, a, n_variants=37, accuracy_floor=0.6,
                        mesh=mesh)
+        assert sm.restricted_steps_run == %d * sm.steps_run
         assert sh["count"] == 37.0
         for k in ("mean", "std", "worst", "best", "yield"):
             np.testing.assert_allclose(sh[k], lo[k], atol=1e-5), k
         np.testing.assert_allclose(sh["hist"], lo["hist"], atol=1e-3)
         print("OK")
-    """) % os.path.abspath(os.path.join(os.path.dirname(__file__),
-                                        os.pardir))
+    """) % (os.path.abspath(os.path.join(os.path.dirname(__file__),
+                                         os.pardir)), assignment, restricted)
     env = dict(os.environ, PYTHONPATH=SRC)
     env.pop("XLA_FLAGS", None)
     res = subprocess.run([sys.executable, "-c", body], env=env,
